@@ -102,5 +102,8 @@ def run_all():
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     for name, us, out in run_all():
         print(f"{name},{us:.0f},{out}")

@@ -281,6 +281,10 @@ def reset_breakers() -> None:
 # the frame runtime so calibration samples (and the bench JSON built from
 # them) attribute time to the path that really ran, not the one requested
 _SERVED = threading.local()
+# process-wide tally of guarded dispatches by (op, serving backend): the
+# chip smoke reads it to prove no kernel dispatch was served by numpy
+_SERVED_COUNTS: Dict[Tuple[str, str], int] = {}
+_SERVED_LOCK = threading.Lock()
 
 
 def note_reset() -> None:
@@ -296,20 +300,60 @@ def served_backend(default: str) -> Tuple[str, Optional[str]]:
     )
 
 
-def _note(bk: str, reason: Optional[str]) -> None:
+def served_counts() -> Dict[Tuple[str, str], int]:
+    """Guarded dispatches so far, keyed (op family, backend that served)."""
+    with _SERVED_LOCK:
+        return dict(_SERVED_COUNTS)
+
+
+def reset_served_counts() -> None:
+    with _SERVED_LOCK:
+        _SERVED_COUNTS.clear()
+
+
+def _tally(op: str, bk: str) -> None:
+    with _SERVED_LOCK:
+        _SERVED_COUNTS[(op, bk)] = _SERVED_COUNTS.get((op, bk), 0) + 1
+
+
+def _note(op: str, bk: str, reason: Optional[str]) -> None:
     _SERVED.backend = bk
     _SERVED.reason = reason
+    _tally(op, bk)
+
+
+# A kernel the platform refuses to lower or compile fails on every call: a
+# numpy fallback would only hide that the device never ran.  Such refusals
+# propagate; run-time failures (resource exhaustion, injected faults) stay
+# absorbed and scored against the breaker.
+_REFUSAL_TYPES = ("LoweringException", "NotImplementedError")
+_REFUSAL_MARKERS = (
+    "pallas tpu lowering",
+    "only interpret mode is supported",
+    "mosaic",
+    "failed to compile",
+)
+
+
+def _is_refusal(exc: BaseException) -> bool:
+    if isinstance(exc, _faults.InjectedFault):
+        return False
+    if type(exc).__name__ in _REFUSAL_TYPES:
+        return True
+    msg = str(exc).lower()
+    return any(m in msg for m in _REFUSAL_MARKERS)
 
 
 def _guarded(op: str, bk: str, kernel_fn: Callable[[], Any],
              fallback_fn: Callable[[], Any]) -> Any:
     """Runtime dispatch guard: breaker gate → fault injection → kernel call;
-    ANY runtime exception is absorbed into a numpy fallback for this dispatch
+    a run-time exception is absorbed into a numpy fallback for this dispatch
     and scored against the (op, backend) breaker.  The foreground interactive
     path rides the same guard, which is what makes user-visible results
-    immune to kernel runtime failures."""
+    immune to kernel runtime failures.  A lowering or compile refusal
+    (``_is_refusal``) raises instead."""
     if not _BOARD.allow(op, bk):
-        _note("numpy", "breaker_open")
+        _note(op, "numpy", "breaker_open")
         return fallback_fn()
     try:
         mode = _faults.fire("kernel", op=op)  # chaos: may raise / sleep
@@ -318,8 +362,10 @@ def _guarded(op: str, bk: str, kernel_fn: Callable[[], Any],
             raise _faults.InjectedFault(f"corrupted kernel output at {op}")
         out = kernel_fn()
     except Exception as exc:
+        if _is_refusal(exc):
+            raise
         _BOARD.record_failure(op, bk, error=f"{type(exc).__name__}: {exc}")
-        _note("numpy", "runtime_error")
+        _note(op, "numpy", "runtime_error")
         logger.warning(
             "kernel dispatch (%s, %s) failed at run time (%s: %s); "
             "numpy fallback for this dispatch",
@@ -327,7 +373,7 @@ def _guarded(op: str, bk: str, kernel_fn: Callable[[], Any],
         )
         return fallback_fn()
     _BOARD.record_success(op, bk)
-    _note(bk, None)
+    _note(op, bk, None)
     return out
 
 
@@ -335,18 +381,21 @@ def _guarded(op: str, bk: str, kernel_fn: Callable[[], Any],
 def _breaker_watch(op: str, bk: str):
     """Batched dispatches don't fall back per-call (the whole batch raises to
     the executor, whose fault boundary quarantines the node) — but their
-    failures must still score the breaker so subsequent planning declines the
-    broken kernel.  Fires the kernel chaos site on entry, like _guarded."""
+    run-time failures must still score the breaker so subsequent planning
+    declines the broken kernel.  A lowering or compile refusal raises without
+    scoring it.  Fires the kernel chaos site on entry, like _guarded."""
     mode = _faults.fire("kernel", op=op)  # may raise — counted below
     try:
         if mode == "corrupt":
             raise _faults.InjectedFault(f"corrupted kernel output at {op}")
         yield
     except Exception as exc:
-        _BOARD.record_failure(op, bk, error=f"{type(exc).__name__}: {exc}")
+        if not _is_refusal(exc):
+            _BOARD.record_failure(op, bk, error=f"{type(exc).__name__}: {exc}")
         raise
     else:
         _BOARD.record_success(op, bk)
+        _tally(op, bk)
 
 
 # --------------------------------------------------------------------------- #
@@ -1451,10 +1500,10 @@ def sharded_available() -> bool:
 
 
 def sharded_stats(table: "PTable", cols: Optional[Sequence[str]] = None):
-    """Merged ColStats for the table's numeric columns via ONE collective
-    dispatch — bit-for-bit ``B.merge_stats`` over per-partition XLA partials.
-    Returns ``None`` when declined (no mesh, <2 partitions, no numeric
-    columns)."""
+    """Merged ColStats for the table's numeric columns from ONE sharded
+    dispatch of per-partition raws, merged on the host by ``B.merge_stats``
+    — bit-for-bit the host path over per-partition XLA partials.  Returns
+    ``None`` when declined (no mesh, <2 partitions, no numeric columns)."""
     from . import dist
 
     if not dist.sharded_available() or len(table.partitions) < 2:
@@ -1464,20 +1513,13 @@ def sharded_stats(table: "PTable", cols: Optional[Sequence[str]] = None):
     )
     if not names:
         return None
-    st = dist.ShardedPTable.from_table(table, names)
-    if st is None:
+    raws = sharded_stats_raws(table, names)
+    if raws is None:
         return None
-
-    def _run():
-        raw = dist.stats_combined(st)  # (C, 5) f64: n, mean, m2, mn, mx
-        return {
-            nm: ColStats(
-                float(r[0]), float(r[1]), float(r[2]), float(r[3]), float(r[4])
-            )
-            for nm, r in zip(names, raw)
-        }
-
-    return _guarded("stats", "sharded", _run, lambda: None)
+    return B.merge_stats([
+        _stats_from_raw(names, np.asarray(raws[i], np.float64))
+        for i in range(len(table.partitions))
+    ])
 
 
 def sharded_stats_raws(table: "PTable", names: Sequence[str]):

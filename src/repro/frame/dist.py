@@ -11,23 +11,20 @@ the combine lowers to collectives.  This module holds the device layer:
   ``(Ppad, C, nb)`` device matrices with ``NamedSharding`` along ``data``
   (partition axis sharded, contiguous blocks of ``pl = Ppad/d`` partitions per
   device), cached on the (immutable) host table;
-* sharded dispatches — describe/mean raws + exact collective combine, groupby
+* sharded dispatches — describe/mean raws (merged on the host), groupby
   segment fold, value_counts psum, per-partition topk winners, and the
   partition-parallel join build/probe.  Each runs ONE shard_map over all
   partitions instead of P per-partition dispatches + a host merge loop.
 
-Bit-for-bit contract: every sharded combine replays the host combine's exact
-f64 operation sequence inside the jit.  The host ``_pairwise_merge`` (iterative
-adjacent pairing) over P partials equals a balanced pow-2 tree over
-``next_pow2(P)`` leaves with empty-ColStats padding at the end (merge with an
-``n == 0`` operand is the identity), so contiguous per-device blocks of pow-2
-size ``pl`` reproduce the host tree's lower levels locally, and ``log2(d)``
-more in-jit levels over the all-gathered subtree roots complete it.  Counts,
-mins and maxes are order-independent in exact arithmetic and ride plain
-``psum``/``pmin``/``pmax``.  Per-partition raws come from the *same* traced
-kernels (:func:`repro.kernels.ops.stats_row_tiled` et al.) the host path
-dispatches, at a shared row bucket whose extra all-masked tiles are exact
-no-ops — so the numbers entering the combine are bit-identical too.
+Bit-for-bit contract: per-partition raws come from the *same* traced kernels
+(:func:`repro.kernels.ops.stats_row_tiled` et al.) the host path dispatches,
+at a shared row bucket whose extra all-masked tiles are exact no-ops — so the
+numbers entering every combine are bit-identical.  The describe/mean merge
+(means, Chan's m2 update) runs on the host over the sharded raws: a TPU
+emulates f64, not to IEEE rounding, so an in-jit f64 merge differed from the
+host merge in the last bits on the chip.  The groupby fold adds f32 partials
+in f64 in global partition order and value_counts psums integer counts —
+exact wherever the sums are exact in f64.
 """
 from __future__ import annotations
 
@@ -43,8 +40,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..jaxcompat import make_mesh
-from ..jaxcompat import shard_map as _shard_map
 from ..kernels import ops
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
@@ -56,36 +51,32 @@ AXIS = "data"
 # --------------------------------------------------------------------------- #
 
 _MESH: Optional[Mesh] = None
-_MESH_FAILED = False
+_MESH_INERT = False
 _MESH_LOCK = threading.Lock()
 
 
 def data_mesh() -> Optional[Mesh]:
     """The process-wide 1-D ``data`` mesh over all local devices, or ``None``
     when sharded execution cannot run (single device, or a non-power-of-two
-    device count — the balanced-tree combine needs pow-2 blocks)."""
-    global _MESH, _MESH_FAILED
+    device count — the balanced-tree combine needs pow-2 blocks).  A failure
+    to enumerate devices or to build the mesh raises: it must not quietly
+    turn the collective path into the host path."""
+    global _MESH, _MESH_INERT
     if _MESH is not None:
         return _MESH
-    if _MESH_FAILED:
+    if _MESH_INERT:
         return None
     with _MESH_LOCK:
         if _MESH is not None:
             return _MESH
-        try:
-            devs = jax.devices()
-        except Exception:
-            _MESH_FAILED = True
-            return None
+        devs = jax.devices()
         d = len(devs)
         if d < 2 or (d & (d - 1)) != 0:
-            _MESH_FAILED = True
+            _MESH_INERT = True
             return None
-        try:
-            _MESH = make_mesh((d,), (AXIS,), devices=devs)
-        except Exception:
-            _MESH_FAILED = True
-            return None
+        _MESH = jax.make_mesh(
+            (d,), (AXIS,), axis_types=(jax.sharding.AxisType.Auto,), devices=devs
+        )
         return _MESH
 
 
@@ -236,53 +227,13 @@ class ShardedPTable:
 
 
 # --------------------------------------------------------------------------- #
-# exact ColStats merge, replayed in-jit (f64)                                  #
-# --------------------------------------------------------------------------- #
-
-
-def _merge_colstats(a, b):
-    """jnp replica of ColStats.merge, vectorised over columns.  Guards mirror
-    the host's n==0 identities for n/mean/m2; min/max need no guards (the
-    empty stats' ±inf neutrals are identities).  NaNs from the 0/0 division in
-    an unselected ``where`` branch are discarded by the select."""
-    an, am, am2, amn, amx = a
-    bn, bm, bm2, bmn, bmx = b
-    n = an + bn
-    delta = bm - am
-    mean_m = am + delta * bn / n
-    m2_m = am2 + bm2 + delta * delta * an * bn / n
-    mean = jnp.where(bn == 0, am, jnp.where(an == 0, bm, mean_m))
-    m2 = jnp.where(bn == 0, am2, jnp.where(an == 0, bm2, m2_m))
-    return (n, mean, m2, jnp.minimum(amn, bmn), jnp.maximum(amx, bmx))
-
-
-def _pairwise_tree(stats):
-    """Balanced adjacent-pair reduction over axis 0 (length must be pow-2) —
-    the host _pairwise_merge tree, one level per halving."""
-    size = stats[0].shape[0]
-    while size > 1:
-        a = tuple(t[0::2] for t in stats)
-        b = tuple(t[1::2] for t in stats)
-        stats = _merge_colstats(a, b)
-        size //= 2
-    return tuple(t[0] for t in stats)
-
-
-def _stats_from_raw_jit(raw64):
-    """In-jit replica of backend._stats_from_raw: (…, 5) f64 raw rows of
-    (count, sum, m2, min, max) → (n, mean, m2, mn, mx) component arrays.
-    count==0 rows already carry (0, 0, 0, +inf, −inf) from the kernel, and
-    0/max(0,1) = 0 reproduces the host's empty-mean of 0.0 exactly."""
-    n = raw64[..., 0]
-    mean = raw64[..., 1] / jnp.maximum(n, 1.0)
-    m2 = jnp.maximum(raw64[..., 2], 0.0)
-    return (n, mean, m2, raw64[..., 3], raw64[..., 4])
-
-
-# --------------------------------------------------------------------------- #
 # sharded dispatches                                                           #
 # --------------------------------------------------------------------------- #
 
+# Every shard_map here runs with check_vma=False: scan carries start
+# replicated and become device-varying, and replicated outputs come from
+# all-gathered or psummed operands — neither is visible to the varying-axes
+# checker.
 _JITS: Dict[tuple, object] = {}
 
 
@@ -292,52 +243,6 @@ def _jit_for(key: tuple, builder):
         fn = builder()
         _JITS[key] = fn
     return fn
-
-
-def _x64():
-    return jax.experimental.enable_x64()
-
-
-def _make_stats_combined(mesh: Mesh, pl: int, C: int, nb: int, d: int):
-    def shard_fn(xs, ms):  # local (pl, C, nb) / (pl, C, nb)
-        rows = [
-            ops.stats_row_tiled(xs[p, c], ms[p, c], ops.TILE)
-            for p in range(pl)
-            for c in range(C)
-        ]
-        raw = jnp.stack(rows).reshape(pl, C, 5).astype(jnp.float64)
-        stats = _stats_from_raw_jit(raw)  # 5 × (pl, C)
-        loc = _pairwise_tree(stats)  # 5 × (C,) — this device's subtree root
-        n_tot = jax.lax.psum(loc[0], AXIS)
-        mn_tot = jax.lax.pmin(loc[3], AXIS)
-        mx_tot = jax.lax.pmax(loc[4], AXIS)
-        g = tuple(jax.lax.all_gather(t, AXIS) for t in loc)  # 5 × (d, C)
-        top = _pairwise_tree(g)
-        return jnp.stack([n_tot, top[1], top[2], mn_tot, mx_tot], axis=1)
-
-    return jax.jit(
-        _shard_map(
-            shard_fn, mesh=mesh,
-            in_specs=(P(AXIS), P(AXIS)), out_specs=P(), check_rep=False,
-        )
-    )
-
-
-def stats_combined(st: ShardedPTable) -> np.ndarray:
-    """One dispatch: per-partition fused stats + exact collective combine.
-    Returns (C, 5) f64 rows of (n, mean, m2, min, max) — the merged ColStats
-    for each column, bit-for-bit the host pairwise merge of per-partition
-    XLA partials."""
-    with _x64():
-        fn = _jit_for(
-            ("stats_combined", st.pl, len(st.names), st.nb),
-            lambda: _make_stats_combined(
-                st.mesh, st.pl, len(st.names), st.nb, st.ppad // st.pl
-            ),
-        )
-        out = np.asarray(fn(st.xs, st.ms))
-    _count("stats")
-    return out
 
 
 def _make_stats_raws(mesh: Mesh, pl: int, C: int, nb: int):
@@ -350,9 +255,9 @@ def _make_stats_raws(mesh: Mesh, pl: int, C: int, nb: int):
         return jnp.stack(rows).reshape(pl, C, 5)
 
     return jax.jit(
-        _shard_map(
+        jax.shard_map(
             shard_fn, mesh=mesh,
-            in_specs=(P(AXIS), P(AXIS)), out_specs=P(AXIS),
+            in_specs=(P(AXIS), P(AXIS)), out_specs=P(AXIS), check_vma=False,
         )
     )
 
@@ -368,7 +273,7 @@ def stats_raws(st: ShardedPTable) -> np.ndarray:
         lambda: _make_stats_raws(st.mesh, st.pl, len(st.names), st.nb),
     )
     out = np.asarray(fn(st.xs, st.ms))
-    _count("stats_raws")
+    _count("stats")
     return out
 
 
@@ -431,10 +336,10 @@ def _make_segment_fold(
         return racc, cacc
 
     return jax.jit(
-        _shard_map(
+        jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(P(AXIS), P(AXIS), P(AXIS)), out_specs=(P(), P()),
-            check_rep=False,
+            check_vma=False,
         )
     )
 
@@ -456,7 +361,7 @@ def segment_fold(
     nb = int(keys.shape[-1])
     S = int(values.shape[1])
     V = int(valids.shape[1])
-    with _x64():
+    with jax.enable_x64(True):
         fn = _jit_for(
             ("segment_fold", pl, d, nb, nbuckets, S, V, modes, valid_idx),
             lambda: _make_segment_fold(
@@ -474,7 +379,7 @@ def _make_topk_winners(mesh: Mesh, pl: int, nb: int, k: int, largest: bool):
         return jnp.stack([ops.topk_body(kf[p], k, largest) for p in range(pl)])
 
     return jax.jit(
-        _shard_map(shard_fn, mesh=mesh, in_specs=(P(AXIS),), out_specs=P(AXIS))
+        jax.shard_map(shard_fn, mesh=mesh, in_specs=(P(AXIS),), out_specs=P(AXIS))
     )
 
 
@@ -524,10 +429,10 @@ def _make_join_build(mesh: Mesh, ml: int):
         return ks, ids_s, dups
 
     return jax.jit(
-        _shard_map(
+        jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(P(AXIS), P(AXIS)), out_specs=(P(AXIS), P(AXIS), P()),
-            check_rep=False,
+            check_vma=False,
         )
     )
 
@@ -568,10 +473,10 @@ def _make_join_probe(mesh: Mesh, ml: int, nb: int):
         return hitc, gid
 
     return jax.jit(
-        _shard_map(
+        jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(P(AXIS), P(AXIS), P()), out_specs=(P(), P()),
-            check_rep=False,
+            check_vma=False,
         )
     )
 
@@ -648,7 +553,7 @@ def make_distributed_describe(mesh: Mesh, axis: str = "data"):
         std = jnp.sqrt(var * n / denom)
         return jnp.stack([n, mean, std, mn, mx])
 
-    sharded = _shard_map(
+    sharded = jax.shard_map(
         _local,
         mesh=mesh,
         in_specs=(P(axis), P(axis)),
@@ -672,7 +577,7 @@ def make_distributed_groupby_sum(mesh: Mesh, n_buckets: int, axis: str = "data")
         counts = jax.ops.segment_sum(c, keys, num_segments=n_buckets)
         return jax.lax.psum(sums, axis), jax.lax.psum(counts, axis)
 
-    sharded = _shard_map(
+    sharded = jax.shard_map(
         _local,
         mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis)),

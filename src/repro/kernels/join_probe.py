@@ -1,18 +1,17 @@
 """Sorted-lookup join probe (broadcast dim-table join, paper §5.1) for TPU.
 
 Hardware adaptation: a hash-table probe is a random gather — the access
-pattern TPUs are worst at.  With the (small, broadcast) right side sorted and
-resident in VMEM, the probe becomes *counting*: for each left key,
+pattern TPUs are worst at.  With the (small, broadcast) right side sorted,
+the probe becomes *counting*: for each left key,
 
     ``pos[i] = #{ j : r_sorted[j] < l_keys[i] }``   (== searchsorted-left)
     ``hit[i] = any(r_sorted[j] == l_keys[i])``
 
-Per grid step we compare a (T,) tile of left keys against a (Bk,) block of
-right keys — a (T × Bk) broadcast compare on the VPU, reduced along the
-bucket axis and accumulated across right blocks (same blocked formulation as
-`segment_reduce`, with comparison matrices instead of one-hots).  No
-data-dependent control flow, no gather: the host gathers right columns once
-with the resulting positions.
+Left keys stream through the grid as ``(tile_rows, 128)`` VMEM tiles; the
+right side streams as blocks of scalars in SMEM.  Each right key is one
+scalar-broadcast compare against a register-resident group of left rows,
+accumulated across right blocks.  No data-dependent control flow, no
+gather: the host gathers right columns once with the resulting positions.
 """
 from __future__ import annotations
 
@@ -22,19 +21,24 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-DEFAULT_TILE = 1024  # left keys per grid step
-DEFAULT_RIGHT_BLOCK = 128  # right keys per block (lane-aligned)
+from .lanes import LANES, SUBLANES, row_layout, to_lanes
+
+DEFAULT_TILE_ROWS = 256  # 32K left keys per grid step
+DEFAULT_RIGHT_BLOCK = 2048  # right keys per SMEM block
+_GROUP_ROWS = 4 * SUBLANES  # left rows held in registers per right sweep
+_UNROLL = 8  # right keys per loop step (right blocks are multiples of 128)
 
 
 def _probe_kernel(
-    l_ref,  # (1, T) f32 left keys
-    r_ref,  # (1, Bk) f32 sorted right keys (NaN padded)
-    pos_ref,  # (1, T) i32 running counts
-    hit_ref,  # (1, T) i32 running any-equal (0/1)
+    l_ref,  # (TR, 128) f32 left keys (NaN padded)
+    r_ref,  # (RB,) f32 sorted right keys in SMEM (NaN padded)
+    pos_ref,  # (TR, 128) i32 running counts
+    hit_ref,  # (TR, 128) i32 running any-equal (0/1)
     *,
-    tile: int,
     right_block: int,
+    group_rows: int,
 ):
     rb = pl.program_id(1)
 
@@ -43,25 +47,38 @@ def _probe_kernel(
         pos_ref[...] = jnp.zeros_like(pos_ref)
         hit_ref[...] = jnp.zeros_like(hit_ref)
 
-    lk = l_ref[0]  # (T,)
-    rk = r_ref[0]  # (Bk,)
-    lt = rk[None, :] < lk[:, None]  # (T, Bk)
-    eq = rk[None, :] == lk[:, None]
-    # int32 accumulation: f32 counts would round away increments past 2^24
-    # rows of right side, silently corrupting the gather positions
-    pos_ref[...] += jnp.sum(lt.astype(jnp.int32), axis=1)[None]
-    hit_ref[...] = jnp.maximum(
-        hit_ref[...], jnp.max(eq.astype(jnp.int32), axis=1)[None]
-    )
+    def group(g, _):
+        rows = pl.ds(pl.multiple_of(g * group_rows, group_rows), group_rows)
+        lk = l_ref[rows, :]
+
+        def right_keys(j, carry):
+            pos, hit = carry
+            for u in range(_UNROLL):  # manual unroll: amortise loop overhead
+                r = r_ref[j * _UNROLL + u]
+                # int32 accumulation: f32 counts would round away increments
+                # past 2^24 rows of right side, corrupting the gather positions
+                pos = pos + jnp.where(r < lk, 1, 0)
+                hit = jnp.maximum(hit, jnp.where(r == lk, 1, 0))
+            return pos, hit
+
+        pos, hit = jax.lax.fori_loop(
+            0, right_block // _UNROLL, right_keys,
+            (pos_ref[rows, :], hit_ref[rows, :]),
+        )
+        pos_ref[rows, :] = pos
+        hit_ref[rows, :] = hit
+        return 0
+
+    jax.lax.fori_loop(0, l_ref.shape[0] // group_rows, group, 0)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("tile", "right_block", "interpret")
+    jax.jit, static_argnames=("tile_rows", "right_block", "interpret")
 )
 def join_probe(
     l_keys: jnp.ndarray,  # f32[n]
     r_sorted: jnp.ndarray,  # f32[m] ascending, unique among finite entries
-    tile: int = DEFAULT_TILE,
+    tile_rows: int = DEFAULT_TILE_ROWS,
     right_block: int = DEFAULT_RIGHT_BLOCK,
     interpret: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -75,34 +92,25 @@ def join_probe(
     exactly."""
     n = l_keys.shape[0]
     m = r_sorted.shape[0]
-    tile = min(tile, n)
-    pad_n = (-n) % tile
-    if pad_n:
-        l_keys = jnp.pad(l_keys, (0, pad_n), constant_values=jnp.nan)
-    right_block = min(right_block, m)
-    pad_m = (-m) % right_block
-    if pad_m:
-        r_sorted = jnp.pad(r_sorted, (0, pad_m), constant_values=jnp.nan)
-    nt = l_keys.shape[0] // tile
-    nrb = r_sorted.shape[0] // right_block
-
+    rows, tr = row_layout(n, tile_rows)
+    group_rows = _GROUP_ROWS if tr % _GROUP_ROWS == 0 else SUBLANES
+    rb = min(right_block, -(-m // LANES) * LANES)
+    mp = -(-m // rb) * rb
+    r = r_sorted.astype(jnp.float32)
+    if mp != m:
+        r = jnp.pad(r, (0, mp - m), constant_values=jnp.nan)
     pos, hit = pl.pallas_call(
-        functools.partial(_probe_kernel, tile=tile, right_block=right_block),
-        grid=(nt, nrb),
+        functools.partial(_probe_kernel, right_block=rb, group_rows=group_rows),
+        grid=(rows // tr, mp // rb),
         in_specs=[
-            pl.BlockSpec((1, tile), lambda t, rb: (t, 0)),
-            pl.BlockSpec((1, right_block), lambda t, rb: (0, rb)),
+            pl.BlockSpec((tr, LANES), lambda t, b: (t, 0)),
+            pl.BlockSpec((rb,), lambda t, b: (b,), memory_space=pltpu.SMEM),
         ],
-        out_specs=[
-            pl.BlockSpec((1, tile), lambda t, rb: (t, 0)),
-            pl.BlockSpec((1, tile), lambda t, rb: (t, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nt, tile), jnp.int32),
-            jax.ShapeDtypeStruct((nt, tile), jnp.int32),
-        ],
+        out_specs=[pl.BlockSpec((tr, LANES), lambda t, b: (t, 0))] * 2,
+        out_shape=[jax.ShapeDtypeStruct((rows, LANES), jnp.int32)] * 2,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
+        ),
         interpret=interpret,
-    )(l_keys.reshape(nt, tile), r_sorted.reshape(1, -1))
-    pos = pos.reshape(-1)[:n]
-    hit = hit.reshape(-1)[:n] > 0
-    return pos, hit
+    )(to_lanes(l_keys.astype(jnp.float32), rows, jnp.nan), r)
+    return pos.reshape(-1)[:n], hit.reshape(-1)[:n] > 0

@@ -404,9 +404,9 @@ def _segment_batch_body(
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """All of a groupby's reductions in one dispatch, via lax.scan over row
     tiles.  Per tile the bucket one-hot is built once; every sum-mode row and
-    every count row rides the same (rows × T) @ (T × buckets) contraction —
-    the XLA mirror of the segment_reduce Pallas kernel's MXU formulation,
-    with temporaries cache-resident instead of n-sized.  min/max rows use a
+    every count row rides the same (rows × T) @ (T × buckets) contraction
+    (the MXU on a TPU), with temporaries cache-resident instead of
+    n-sized.  min/max rows use a
     masked select + reduce on the same one-hot (no scatter: XLA:CPU scatter
     is serial and catastrophically slow)."""
     n = keys.shape[0]
@@ -427,7 +427,10 @@ def _segment_batch_body(
         oh = ohb.astype(jnp.float32)
         mf = [m.astype(jnp.float32) for m in mi]
         gemm_rows = [vi[s] * mf[valid_idx[s]] for s in sum_rows] + mf
-        acc_rows = jnp.stack(gemm_rows) @ oh  # (len(sum_rows)+V, nb)
+        # HIGHEST: the TPU's default f32 matmul rounds operands to bf16
+        acc_rows = jnp.matmul(  # (len(sum_rows)+V, nb)
+            jnp.stack(gemm_rows), oh, precision=jax.lax.Precision.HIGHEST
+        )
         sums = sums + acc_rows[: len(sum_rows)]
         cnts = cnts + acc_rows[len(sum_rows):]
         mms = []
@@ -490,7 +493,7 @@ def segment_reduce_batch(
     b = backend()
     if b == "xla":
         # exact bucket count: the GEMM width is the dominant cost and XLA
-        # needs no lane alignment (the pallas path below keeps 128-rounding).
+        # needs no lane alignment (the pallas kernel pads buckets to lanes).
         # Row length pads to a fixed-_TILE tile for the same bucket-invariance
         # reason as masked_stats_batch: padded rows (key 0, valid False) are
         # exact-neutral in the one-hot GEMM and min/max selects, so the fused
@@ -509,18 +512,17 @@ def segment_reduce_batch(
             tuple(modes), tuple(int(i) for i in valid_idx), _TILE,
         )
         return reds, cnts
-    nbuckets = max(128, -(-int(num_buckets) // 128) * 128)
     interp = b == "interpret"
     red_rows = [
         _segment_pallas(
-            keys, values[s], valids[valid_idx[s]], nbuckets,
+            keys, values[s], valids[valid_idx[s]], int(num_buckets),
             mode=modes[s], interpret=interp,
         )[0][:num_buckets]
         for s in range(len(values))
     ]
     cnt_rows = [
         _segment_pallas(
-            keys, jnp.zeros_like(keys, jnp.float32), valids[v], nbuckets,
+            keys, jnp.zeros_like(keys, jnp.float32), valids[v], int(num_buckets),
             mode="sum", interpret=interp,
         )[1][:num_buckets]
         for v in range(len(valids))

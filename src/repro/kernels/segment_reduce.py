@@ -1,11 +1,13 @@
-"""Segment reduction (groupby-aggregate / MoE combine) for TPU.
+"""Segment reduction (groupby-aggregate / value_counts) for TPU.
 
 Hardware adaptation: GPU groupby kernels scatter with atomics; TPUs have no
-atomics and hate random scatter.  We reformulate the reduction as a blocked
-**one-hot × matmul**: for a tile of T rows and a bucket block of Bk buckets,
-``onehot[t, bk] = (keys[t] == bucket)`` and ``sums_block += values · onehot``
-— a (1×T)·(T×Bk) contraction that runs on the MXU.  Row tiles stream through
-the innermost grid dimension, accumulating into the output bucket block.
+atomics and hate random scatter.  The reduction runs as a dense bucket sweep
+instead: for a ``(tile_rows, 128)`` block of rows and a block of 128
+buckets (one lane each), every bucket ``b`` is a full-tile compare
+``keys == b`` and a masked reduce, and the result lands in lane ``b`` of
+the bucket block's (8, 128) accumulator.  No data-dependent addressing, no
+scatter.  Row tiles stream through the innermost grid dimension,
+accumulating into the output bucket block.
 """
 from __future__ import annotations
 
@@ -15,21 +17,22 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-DEFAULT_TILE = 512  # rows per grid step
-DEFAULT_BUCKET_BLOCK = 128  # buckets per output block (lane-aligned)
+from .lanes import LANES, SUBLANES, row_layout, tile_max, tile_min, tile_sum, to_lanes
+
+DEFAULT_TILE_ROWS = 256  # 32K rows per grid step
+_VREG = (SUBLANES, LANES)
+_INIT = {"sum": 0.0, "min": jnp.inf, "max": -jnp.inf}
 
 
 def _segment_kernel(
-    keys_ref,  # (1, T) int32
-    vals_ref,  # (1, T) f32
-    valid_ref,  # (1, T) bool
-    out_ref,  # (1, Bk) f32 reduced
-    cnt_ref,  # (1, Bk) f32 counts
+    keys_ref,  # (TR, 128) int32
+    vals_ref,  # (TR, 128) f32
+    valid_ref,  # (TR, 128) f32, 1.0 = valid
+    out_ref,  # (8, 128) f32: lane j = bucket bi*128 + j (every row alike)
+    cnt_ref,  # (8, 128) f32 counts, same layout
     *,
-    tile: int,
-    bucket_block: int,
-    num_row_tiles: int,
     mode: str,
 ):
     bi = pl.program_id(0)
@@ -37,38 +40,37 @@ def _segment_kernel(
 
     @pl.when(t == 0)
     def _init():
-        init = {"sum": 0.0, "min": jnp.inf, "max": -jnp.inf}[mode]
-        out_ref[...] = jnp.full_like(out_ref, init)
-        cnt_ref[...] = jnp.zeros_like(cnt_ref)
+        out_ref[...] = jnp.full(_VREG, _INIT[mode], jnp.float32)
+        cnt_ref[...] = jnp.zeros(_VREG, jnp.float32)
 
-    keys = keys_ref[0]  # (T,)
-    vals = vals_ref[0].astype(jnp.float32)
-    valid = valid_ref[0]
+    keys = keys_ref[...]
+    vals = vals_ref[...]
+    valid = valid_ref[...] > 0.0
+    lane = jax.lax.broadcasted_iota(jnp.int32, _VREG, 1)
+    base = bi * LANES
 
-    bucket_ids = bi * bucket_block + jax.lax.broadcasted_iota(
-        jnp.int32, (tile, bucket_block), 1
-    )
-    onehot = (keys[:, None] == bucket_ids) & valid[:, None]  # (T, Bk)
-    oh_f = onehot.astype(jnp.float32)
+    def bucket(j, carry):
+        red, cnt = carry
+        hit = (keys == base + j) & valid
+        sel = lane == j
+        cnt = jnp.where(sel, cnt + tile_sum(jnp.where(hit, 1.0, 0.0)), cnt)
+        if mode == "sum":
+            red = jnp.where(sel, red + tile_sum(jnp.where(hit, vals, 0.0)), red)
+        elif mode == "min":
+            r = tile_min(jnp.where(hit, vals, jnp.inf))
+            red = jnp.where(sel, jnp.minimum(red, r), red)
+        else:
+            r = tile_max(jnp.where(hit, vals, -jnp.inf))
+            red = jnp.where(sel, jnp.maximum(red, r), red)
+        return red, cnt
 
-    cnt_ref[...] += jnp.sum(oh_f, axis=0, keepdims=True)
-    if mode == "sum":
-        # (1,T) @ (T,Bk) on the MXU
-        out_ref[...] += jax.lax.dot_general(
-            vals[None, :], oh_f, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-    elif mode == "min":
-        contrib = jnp.where(onehot, vals[:, None], jnp.inf)
-        out_ref[...] = jnp.minimum(out_ref[...], jnp.min(contrib, axis=0)[None])
-    elif mode == "max":
-        contrib = jnp.where(onehot, vals[:, None], -jnp.inf)
-        out_ref[...] = jnp.maximum(out_ref[...], jnp.max(contrib, axis=0)[None])
+    red, cnt = jax.lax.fori_loop(0, LANES, bucket, (out_ref[...], cnt_ref[...]))
+    out_ref[...] = red
+    cnt_ref[...] = cnt
 
 
 @functools.partial(
-    jax.jit,
-    static_argnames=("num_buckets", "mode", "tile", "bucket_block", "interpret"),
+    jax.jit, static_argnames=("num_buckets", "mode", "tile_rows", "interpret"),
 )
 def segment_reduce(
     keys: jnp.ndarray,  # int32[n]
@@ -76,52 +78,26 @@ def segment_reduce(
     valid: jnp.ndarray,  # bool[n]
     num_buckets: int,
     mode: str = "sum",
-    tile: int = DEFAULT_TILE,
-    bucket_block: int = DEFAULT_BUCKET_BLOCK,
+    tile_rows: int = DEFAULT_TILE_ROWS,
     interpret: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Returns (reduced[num_buckets], counts[num_buckets])."""
-    n = keys.shape[0]
-    tile = min(tile, n)
-    pad_n = (-n) % tile
-    if pad_n:
-        keys = jnp.pad(keys, (0, pad_n), constant_values=-1)
-        values = jnp.pad(values, (0, pad_n))
-        valid = jnp.pad(valid, (0, pad_n), constant_values=False)
-    n_padded = keys.shape[0]
-    bucket_block = min(bucket_block, num_buckets)
-    pad_b = (-num_buckets) % bucket_block
-    nb = num_buckets + pad_b
-    num_row_tiles = n_padded // tile
-    num_bucket_blocks = nb // bucket_block
-
-    keys2 = keys.reshape(num_row_tiles, tile)
-    vals2 = values.reshape(num_row_tiles, tile)
-    valid2 = valid.reshape(num_row_tiles, tile)
-
-    grid = (num_bucket_blocks, num_row_tiles)
+    if mode not in _INIT:
+        raise ValueError(f"segment_reduce mode {mode!r}")
+    rows, tr = row_layout(keys.shape[0], tile_rows)
+    keys2 = to_lanes(keys.astype(jnp.int32), rows, -1)
+    vals2 = to_lanes(values.astype(jnp.float32), rows, 0.0)
+    valid2 = to_lanes(valid.astype(jnp.float32), rows, 0.0)
+    nbb = -(-num_buckets // LANES)
     out, cnt = pl.pallas_call(
-        functools.partial(
-            _segment_kernel,
-            tile=tile,
-            bucket_block=bucket_block,
-            num_row_tiles=num_row_tiles,
-            mode=mode,
+        functools.partial(_segment_kernel, mode=mode),
+        grid=(nbb, rows // tr),
+        in_specs=[pl.BlockSpec((tr, LANES), lambda b, t: (t, 0))] * 3,
+        out_specs=[pl.BlockSpec(_VREG, lambda b, t: (0, b))] * 2,
+        out_shape=[jax.ShapeDtypeStruct((SUBLANES, nbb * LANES), jnp.float32)] * 2,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
         ),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, tile), lambda b, t: (t, 0)),
-            pl.BlockSpec((1, tile), lambda b, t: (t, 0)),
-            pl.BlockSpec((1, tile), lambda b, t: (t, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bucket_block), lambda b, t: (0, b)),
-            pl.BlockSpec((1, bucket_block), lambda b, t: (0, b)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, nb), jnp.float32),
-            jax.ShapeDtypeStruct((1, nb), jnp.float32),
-        ],
         interpret=interpret,
     )(keys2, vals2, valid2)
     return out[0, :num_buckets], cnt[0, :num_buckets]

@@ -26,7 +26,6 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import RunConfig, all_cells, get_config, get_shape
-from repro.jaxcompat import set_mesh
 from repro.launch.mesh import make_production_mesh
 from repro.launch.roofline import analyze, model_flops_for
 from repro.launch.specs import decode_input_specs, train_input_specs
@@ -91,7 +90,7 @@ def dryrun_cell(
     )
 
     t0 = time.time()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             in_shapes, in_specs = train_input_specs(cfg, shape, ctx)
             step_fn, _ = make_train_step(cfg, run, mesh=mesh, use_ep=True)

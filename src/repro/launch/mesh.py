@@ -1,12 +1,17 @@
 """Production mesh construction.
 
 A FUNCTION, not a module-level constant — importing this module never touches
-jax device state (required by the dry-run contract).  Mesh construction goes
-through :mod:`repro.jaxcompat` so the same code runs across the
-``axis_types`` / ``AxisType`` jax API drift."""
+jax device state (required by the dry-run contract).  Every axis is
+``AxisType.Auto``: call sites pass explicit ``NamedSharding``s."""
 from __future__ import annotations
 
-from repro.jaxcompat import make_mesh as _make_mesh
+import jax
+
+
+def _make_mesh(shape, axes):
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

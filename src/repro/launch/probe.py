@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from ..jaxcompat import set_mesh
 from ..configs.base import ModelConfig, RunConfig
 from ..kernels import ops as kops
 from ..models.base import ShardCtx, tree_specs_to_shapes
@@ -109,7 +108,7 @@ def probe_block(
 
     kops.set_xla_unroll(True)
     try:
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             if kind == "train":
 
                 def fn(x, params, pos):
@@ -187,7 +186,7 @@ def probe_outer(
         run = dataclasses.replace(run, shape=shape)
     kops.set_xla_unroll(True)
     try:
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             if kind == "train":
                 in_shapes, in_specs = train_input_specs(cfg0, shape, ctx)
 
@@ -261,7 +260,7 @@ def probe_optimizer(
     def fn(params, grads, state):
         return adamw_update(opt, params, grads, state)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(
             fn,
             in_shardings=(

@@ -14,8 +14,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..jaxcompat import shard_map as _shard_map
-
 from ..configs.base import ModelConfig
 from ..kernels import ops as kops
 from .base import ParamSpec, ShardCtx, matrix_spec, replicated_spec
@@ -197,7 +195,7 @@ def _split_s_decode(q, k_cache, v_cache, pos, mesh, ctx):
         o, m, l = partial_decode_attention(q_loc, k_loc, v_loc, valid)
         return combine_partial_attention(o, m, l, ctx.model_axis)
 
-    return _shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(

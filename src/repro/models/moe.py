@@ -21,8 +21,6 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..jaxcompat import shard_map as _shard_map
-
 from ..configs.base import ModelConfig
 from .base import ParamSpec, ShardCtx, matrix_spec, replicated_spec
 
@@ -163,7 +161,7 @@ def moe_ffn_sharded(
     def body(p, xl):
         return moe_ffn_ep(p, cfg, xl, ctx)
 
-    return _shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(param_specs, x_spec),

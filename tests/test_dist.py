@@ -342,6 +342,20 @@ def test_single_device_paths_inert():
     assert t.shard() is None
 
 
+def test_data_mesh_failure_raises(monkeypatch):
+    """A device or mesh failure surfaces: it must not read as "no mesh" and
+    quietly turn the collective path into the host path."""
+    monkeypatch.setattr(dist, "_MESH", None)
+    monkeypatch.setattr(dist, "_MESH_INERT", False)
+
+    def no_backend():
+        raise RuntimeError("device enumeration failed")
+
+    monkeypatch.setattr(dist.jax, "devices", no_backend)
+    with pytest.raises(RuntimeError, match="device enumeration failed"):
+        dist.data_mesh()
+
+
 # --------------------------------------------------------------------------- #
 # subprocess re-run under a forced 8-device host platform                      #
 # --------------------------------------------------------------------------- #

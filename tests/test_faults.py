@@ -271,6 +271,36 @@ def test_served_backend_labels_fallback(catalog):
         assert BK.served_backend("xla") == ("xla", None)
 
 
+def test_served_counts_tally_fallbacks_by_op(catalog):
+    part = _part(catalog)
+    BK.reset_served_counts()
+    plan = FaultPlan([FaultSpec("kernel", rate=1.0, max_fires=1)], seed=0)
+    with faults.scope(plan):
+        BK.partial_stats(part, backend="xla")
+        BK.partial_stats(part, backend="xla")
+    assert BK.served_counts() == {("stats", "numpy"): 1, ("stats", "xla"): 1}
+
+
+@pytest.mark.parametrize("batched", [False, True])
+def test_lowering_refusal_raises_instead_of_falling_back(catalog, batched):
+    """A kernel the platform refuses to lower fails on every call: it must
+    surface, not turn into a silent numpy answer.  Here: the Mosaic backend
+    on a host without a TPU."""
+    import jax
+
+    if jax.default_backend() == "tpu":
+        pytest.skip("the pallas backend lowers on a TPU")
+    part = _part(catalog)
+    with pytest.raises(ValueError, match="interpret mode"):
+        if batched:
+            dispatch, _ = BK.plan_stats_batch([part, part], backend="pallas")
+            dispatch()
+        else:
+            BK.partial_stats(part, backend="pallas")
+    snap = BK.breaker_board().snapshot().get("stats|pallas", {})
+    assert not snap.get("failures") and not snap.get("fallbacks")
+
+
 # --------------------------------------------------------------------------- #
 # engine crash isolation + quarantine (simulation mode: deterministic)         #
 # --------------------------------------------------------------------------- #

@@ -40,12 +40,13 @@ def test_cost_analysis_is_per_device():
         pytest.skip("needs >1 device (run under dryrun env)")
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from repro.jaxcompat import make_mesh, set_mesh
-
-    mesh = make_mesh((jax.device_count(),), ("data",))
+    mesh = jax.make_mesh(
+        (jax.device_count(),), ("data",),
+        axis_types=(jax.sharding.AxisType.Auto,),
+    )
     x = jax.ShapeDtypeStruct((64, 128), jnp.float32)
     w = jax.ShapeDtypeStruct((128, 64), jnp.float32)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         c = (
             jax.jit(
                 lambda x, w: x @ w,
@@ -116,7 +117,6 @@ def test_dryrun_cell_tiny_mesh_subprocess():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax
         from repro.configs import RunConfig, get_shape, get_smoke_config
-        from repro.jaxcompat import set_mesh
         from repro.launch.mesh import make_mesh
         from repro.launch.roofline import cost_analysis_dict
         from repro.launch.specs import train_input_specs
@@ -137,7 +137,7 @@ def test_dryrun_cell_tiny_mesh_subprocess():
             lambda s: NamedSharding(mesh, s), t,
             is_leaf=lambda x: isinstance(x, P))
         step, _ = make_train_step(cfg, run, mesh=mesh)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             c = jax.jit(step, in_shardings=(named(pspec), named(ospec),
                                             named(ispec))).lower(
                 ps, os_, ins).compile()
@@ -164,14 +164,14 @@ def test_distributed_frame_ops_subprocess():
         from repro.frame.dist import (
             make_distributed_describe, make_distributed_groupby_sum,
             shard_column)
-        from repro.jaxcompat import make_mesh, set_mesh
-        mesh = make_mesh((8,), ("data",))
+        mesh = jax.make_mesh(
+            (8,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
         rng = np.random.default_rng(0)
         n, nb = 4096, 16
         x = jnp.asarray(rng.normal(size=n), jnp.float32)
         m = jnp.asarray(rng.uniform(size=n) > 0.25)
         keys = jnp.asarray(rng.integers(0, nb, n), jnp.int32)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             desc = make_distributed_describe(mesh)
             out = np.asarray(desc(shard_column(mesh, x), shard_column(mesh, m)))
             xs = np.asarray(x)[np.asarray(m)]

@@ -144,9 +144,9 @@ def test_elastic_restore_resharding(tmp_path):
     # restore with an explicit (trivial single-device) sharding fn
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from repro.jaxcompat import make_mesh
-
-    mesh = make_mesh((1,), ("data",))
+    mesh = jax.make_mesh(
+        (1,), ("data",), axis_types=(jax.sharding.AxisType.Auto,)
+    )
     out = m.restore(tree, sharding_fn=lambda key: NamedSharding(mesh, P()))
     np.testing.assert_array_equal(np.asarray(out["w"]), np.asarray(tree["w"]))
 
