@@ -38,7 +38,6 @@ import gc
 import hashlib
 import json
 import sys
-import threading
 import time
 from pathlib import Path
 
@@ -173,25 +172,7 @@ CHECKS = {
 # --------------------------------------------------------------------------- #
 
 
-class CompileClock:
-    """Seconds JAX spends tracing, lowering and compiling, from any thread."""
-
-    def __init__(self):
-        import jax
-
-        self.seconds = 0.0
-        self.compiles = 0
-        self._lock = threading.Lock()
-        jax.monitoring.register_event_duration_secs_listener(self._on_event)
-
-    def _on_event(self, event: str, duration: float, **_) -> None:
-        if event.startswith("/jax/core/compile/"):
-            with self._lock:
-                self.seconds += duration
-                self.compiles += event.endswith("backend_compile_duration")
-
-
-def interactions(backend: str, seed: int, clock: CompileClock, think: bool = True):
+def interactions(backend: str, seed: int, clock, think: bool = True):
     """Run the loop once in a fresh session; returns (results, latencies,
     compile seconds within each latency, session metrics)."""
     from repro.frame import Session
@@ -287,6 +268,8 @@ def emit(tag: str, payload: dict) -> None:
 def run_single(args, dev) -> list:
     from repro.frame import backend as BK
 
+    from repro.obs import CompileClock, split_since
+
     failures = []
     clock = CompileClock()
     t0 = time.perf_counter()
@@ -298,8 +281,9 @@ def run_single(args, dev) -> list:
     for backend in ("pallas", "xla"):
         BK.reset_breakers()
         BK.reset_served_counts()
-        t0, n0 = time.perf_counter(), clock.compiles
+        t0, split0 = time.perf_counter(), clock.split()
         got, lat, comp, metrics = interactions(backend, args.seed, clock)
+        split = split_since(clock, split0)
         bad = [f"{name} differs from numpy" for name, check in CHECKS.items()
                if not check(got[name], ref[name])]
         bad += phase_faults(metrics) + dispatch_faults(backend, KERNEL_FAMILIES)
@@ -307,7 +291,8 @@ def run_single(args, dev) -> list:
             "seconds": time.perf_counter() - t0,
             "latency_s": lat,
             "compile_s": comp,
-            "compiles": clock.compiles - n0,
+            "compiles": split["compile_n"],
+            "compile_split": split,
             "served": {f"{op}|{bk}": n
                        for (op, bk), n in sorted(BK.served_counts().items())},
             "metrics": metrics,

@@ -22,9 +22,11 @@ from __future__ import annotations
 import logging
 import threading
 import time
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
+from .. import obs
 from . import faults
 from .cache import EvictionPolicy, MaterializedCache
 from .clock import Clock, RealClock, VirtualClock
@@ -52,7 +54,6 @@ from .thinktime import ThinkTimeModel
 class InteractionRecord:
     label: str
     latency_s: float
-    ops_executed: int
     partial: bool  # served via the head/tail partial-result path
     at: float
     tenant: Optional[str] = None  # multi-tenant serving attribution
@@ -317,64 +318,82 @@ class Engine:
     def display(self, node: Node, tenant: Optional[str] = None) -> Any:
         """Execute an interaction: critical path only, everything else deferred."""
         node.is_interaction = True
-        self._pause_worker()
-        try:
-            with self._lock:
-                # record think time since previous output
-                now = self.clock.now()
-                if self._last_output_at is not None:
-                    dt = now - self._last_output_at
-                    if dt > 0:
-                        self.think_time.update(dt)
-                        self.metrics.think_s += dt
-
-                t0 = self.clock.now()
-                n_exec_before = self.executor.stats.nodes_completed
-                partial = False
-                if not self.opportunistic:
-                    # eager baseline: execute *everything specified so far*
-                    # (the paper's status-quo semantics)
-                    for n in self.dag.topological():
-                        if n.nid <= node.nid and n.nid not in self.cache:
-                            self._ensure(n)
-                    value = self.cache.get(node)
-                else:
-                    value = None
-                    if self.partial_results:
-                        impl = (
-                            self.registry[node.op]
-                            if node.op in self.registry
-                            else None
-                        )
-                        if impl is not None and impl.fast_interaction is not None:
-                            value = impl.fast_interaction(node)
-                            if value is not None:
-                                self.cache.put(node, value)
+        with obs.span("engine.display", rid=obs.new_request(), node=node.nid,
+                      cached=int(node.nid in self.cache)):
+            self._pause_worker()
+            try:
+                with self._locked():
+                    self._note_think_time()
+                    t0 = self.clock.now()
+                    partial = False
+                    if not self.opportunistic:
+                        # eager baseline: execute *everything specified so
+                        # far* (the paper's status-quo semantics)
+                        for n in self.dag.topological():
+                            if n.nid <= node.nid and n.nid not in self.cache:
+                                self._ensure(n)
+                        value = self.cache.get(node)
+                    else:
+                        value = None
+                        if self.partial_results:
+                            value = self._fast_path(node)
+                            partial = value is not None
                         if value is None:
-                            value = self._try_partial_headtail(node)
-                        partial = value is not None
-                    if value is None:
-                        value = self._ensure(node)
-                latency = self.clock.now() - t0
-                self.metrics.sync_wait_s += latency
-                self.metrics.interactions.append(
-                    InteractionRecord(
-                        label=node.label,
-                        latency_s=latency,
-                        ops_executed=self.executor.stats.nodes_completed
-                        - n_exec_before,
-                        partial=partial,
-                        at=self.clock.now(),
-                        tenant=tenant,
-                    )
-                )
-                self.speculation.on_critical_path_executed(
-                    critical_path(self.dag, node)
-                )
-                self._last_output_at = self.clock.now()
-                return value
+                            value = self._ensure(node)
+                    self._record_interaction(node, t0, partial, tenant)
+                    return value
+            finally:
+                self._resume_worker()
+
+    def _fast_path(self, node: Node) -> Optional[Any]:
+        """The operator's interaction fast path, then the head/tail partial
+        path; None when neither applies."""
+        with obs.span("engine.fast_path"):
+            impl = self.registry[node.op] if node.op in self.registry else None
+            if impl is not None and impl.fast_interaction is not None:
+                value = impl.fast_interaction(node)
+                if value is not None:
+                    self.cache.put(node, value)
+                    return value
+            return self._try_partial_headtail(node)
+
+    @contextmanager
+    def _locked(self):
+        """The engine lock, its wait timed as ``engine.lock_wait``."""
+        with obs.span("engine.lock_wait"):
+            self._lock.acquire()
+        try:
+            yield
         finally:
-            self._resume_worker()
+            self._lock.release()
+
+    def _note_think_time(self) -> None:
+        """Feed the think time since the previous output to the model."""
+        now = self.clock.now()
+        if self._last_output_at is not None:
+            dt = now - self._last_output_at
+            if dt > 0:
+                self.think_time.update(dt)
+                self.metrics.think_s += dt
+
+    def _record_interaction(self, node: Node, t0: float, partial: bool,
+                            tenant: Optional[str], progressive: bool = False) -> None:
+        """Latency record and speculation hooks of a shown interaction."""
+        latency = self.clock.now() - t0
+        self.metrics.sync_wait_s += latency
+        self.metrics.interactions.append(
+            InteractionRecord(
+                label=node.label,
+                latency_s=latency,
+                partial=partial,
+                at=self.clock.now(),
+                tenant=tenant,
+                progressive=progressive,
+            )
+        )
+        with obs.span("engine.speculate"):
+            self.speculation.on_critical_path_executed(critical_path(self.dag, node))
+        self._last_output_at = self.clock.now()
 
     # ---- progressive interactions (bounded estimates, upgrade in place) ------
     def interact(
@@ -411,77 +430,53 @@ class Engine:
         yet.  Parents ARE materialised (they're on the critical path of any
         estimate); only the node's own partitions are progressive."""
         node.is_interaction = True
-        self._pause_worker()
-        try:
-            with self._lock:
-                now = self.clock.now()
-                if self._last_output_at is not None:
-                    dt = now - self._last_output_at
-                    if dt > 0:
-                        self.think_time.update(dt)
-                        self.metrics.think_s += dt
-                t0 = self.clock.now()
-                n_exec_before = self.executor.stats.nodes_completed
-                impl = self.registry[node.op]
-                cached = self.cache.peek(node.nid)
-                if cached is not None and not faults.is_corrupt(cached):
-                    pr = ProgressiveResult(
-                        self, node, inputs=[], combine=None, total_units=0,
-                        tenant=tenant,
-                    )
-                else:
-                    inputs = (
-                        [self._ensure(p) for p in node.parents]
-                        if impl.needs_inputs
-                        else []
-                    )
-                    units = impl.units(node, inputs)
-                    prog = self.partials.get(node.nid)
-                    if prog is None or prog.total_units != len(units):
-                        prog = PartialProgress(total_units=len(units))
-                        self.partials[node.nid] = prog
-                    combine = (
-                        impl.running_combine(node, inputs)
-                        if impl.running_combine is not None
-                        else None
-                    )
-                    pr = ProgressiveResult(
-                        self, node, inputs=inputs, combine=combine,
-                        total_units=len(units), tenant=tenant,
-                    )
-                    pr._units = units
-                    # replay checkpointed partials, then stream the rest
-                    for i in sorted(prog.results):
-                        pr._on_unit(i, prog.results[i])
-                    self.executor.progress_listeners[node.nid] = pr._on_unit
-                    if pr.n_units == 0 and len(units) > 0:
-                        k = (
-                            seed_units
-                            if seed_units is not None
-                            else max(1, len(units) // 16)
-                        )
-                        self._progressive_step(pr, k)
-                latency = self.clock.now() - t0
-                self.metrics.sync_wait_s += latency
-                self.metrics.interactions.append(
-                    InteractionRecord(
-                        label=node.label,
-                        latency_s=latency,
-                        ops_executed=self.executor.stats.nodes_completed
-                        - n_exec_before,
-                        partial=True,
-                        at=self.clock.now(),
-                        tenant=tenant,
-                        progressive=True,
-                    )
-                )
-                self.speculation.on_critical_path_executed(
-                    critical_path(self.dag, node)
-                )
-                self._last_output_at = self.clock.now()
-                return pr
-        finally:
-            self._resume_worker()
+        with obs.span("engine.display", rid=obs.new_request(), node=node.nid,
+                      cached=int(node.nid in self.cache)):
+            self._pause_worker()
+            try:
+                with self._locked():
+                    self._note_think_time()
+                    t0 = self.clock.now()
+                    pr = self._progressive_result(node, tenant, seed_units)
+                    self._record_interaction(node, t0, True, tenant, progressive=True)
+                    return pr
+            finally:
+                self._resume_worker()
+
+    def _progressive_result(self, node: Node, tenant: Optional[str],
+                            seed_units: Optional[int]) -> ProgressiveResult:
+        """The channel of a progressive interaction, seeded with a first
+        sample of partitions when none are done yet.  Caller holds the lock."""
+        impl = self.registry[node.op]
+        cached = self.cache.peek(node.nid)
+        if cached is not None and not faults.is_corrupt(cached):
+            return ProgressiveResult(
+                self, node, inputs=[], combine=None, total_units=0, tenant=tenant,
+            )
+        inputs = [self._ensure(p) for p in node.parents] if impl.needs_inputs else []
+        units = impl.units(node, inputs)
+        prog = self.partials.get(node.nid)
+        if prog is None or prog.total_units != len(units):
+            prog = PartialProgress(total_units=len(units))
+            self.partials[node.nid] = prog
+        combine = (
+            impl.running_combine(node, inputs)
+            if impl.running_combine is not None
+            else None
+        )
+        pr = ProgressiveResult(
+            self, node, inputs=inputs, combine=combine,
+            total_units=len(units), tenant=tenant,
+        )
+        pr._units = units
+        # replay checkpointed partials, then stream the rest
+        for i in sorted(prog.results):
+            pr._on_unit(i, prog.results[i])
+        self.executor.progress_listeners[node.nid] = pr._on_unit
+        if pr.n_units == 0 and len(units) > 0:
+            k = seed_units if seed_units is not None else max(1, len(units) // 16)
+            self._progressive_step(pr, k)
+        return pr
 
     def _progressive_step(self, pr: ProgressiveResult, max_units: int) -> None:
         """Execute up to ``max_units`` missing partitions of ``pr.node`` in
@@ -793,7 +788,8 @@ class Engine:
 
     def _pause_worker(self) -> None:
         if self._worker is not None:
-            self._worker.pause()
+            with obs.span("engine.pause_ack"):
+                self._worker.pause()
 
     def _resume_worker(self) -> None:
         if self._worker is not None:
@@ -895,7 +891,7 @@ class _BackgroundWorker:
                 continue
             node = None
             try:
-                with eng._lock:
+                with obs.span("worker.pick"), eng._lock:
                     node = eng.scheduler.pick(
                         eng.cache.executed_ids(), now=eng.clock.now()
                     )
@@ -905,19 +901,21 @@ class _BackgroundWorker:
                     self._work.wait(timeout=0.05)
                     self._paused.clear()
                     continue
-                with eng._lock:
+                rid = ("node", node.nid)
+                with obs.span("worker.fetch", rid=rid), eng._lock:
                     inputs = eng._background_inputs(node)
                 t0 = time.monotonic()
-                value = eng.executor.execute(
-                    node,
-                    inputs,
-                    eng.partials,
-                    preempt_check=self._pause_req.is_set,
-                    batch_budget_s=eng._batch_budget_s(),
-                )
+                with obs.span("worker.execute", rid=rid):
+                    value = eng.executor.execute(
+                        node,
+                        inputs,
+                        eng.partials,
+                        preempt_check=self._pause_req.is_set,
+                        batch_budget_s=eng._batch_budget_s(),
+                    )
                 if faults.is_corrupt(value):
                     raise faults.CorruptResult(node.label)
-                with eng._lock:
+                with obs.span("worker.store", rid=rid), eng._lock:
                     eng.cache.put(node, value)
                     eng.scheduler.clear_quarantine(node.nid)
                     eng.metrics.background_busy_s += time.monotonic() - t0

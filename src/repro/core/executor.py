@@ -31,6 +31,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
+from .. import obs
 from . import faults
 from .faults import CorruptResult
 
@@ -245,7 +246,7 @@ class Executor:
         """
         before = self.stats.units_run
         try:
-            with faults.scope(self.fault_plan):
+            with obs.span("exec.node", node=node.nid), faults.scope(self.fault_plan):
                 return self._execute(
                     node, inputs, partials, preempt_check, budget_s, batch_budget_s
                 )
@@ -300,13 +301,12 @@ class Executor:
                     # is lost (paper's worst case = one partition)
                     self.stats.units_preempted_lost += 1
                     raise Preempted(node.label)
-            t0 = time.monotonic()
-            mode = faults.fire("exec.unit", op=node.op)  # may raise / sleep
-            result = unit.fn()
+            with obs.span("exec.unit", unit=i) as sp:
+                mode = faults.fire("exec.unit", op=node.op)  # may raise / sleep
+                result = unit.fn()
             if mode == "corrupt":
                 result = faults.corrupt(result)
-            wall = time.monotonic() - t0
-            dur = unit.cost_s if self.clock.virtual else wall
+            dur = unit.cost_s if self.clock.virtual else sp.ns * 1e-9
             self.clock.advance(unit.cost_s)
             spent += dur
             self._store_unit(node, prog, i, result)
@@ -316,7 +316,8 @@ class Executor:
             c = impl.combine_cost(node, inputs)
             self.clock.advance(c)
             spent += c if self.clock.virtual else 0.0
-        value = impl.combine(node, inputs, prog.ordered())
+        with obs.span("exec.combine"):
+            value = impl.combine(node, inputs, prog.ordered())
         total = (self.clock.now() - started) if self.clock.virtual else spent
         self.cost_model.observe(node, max(total, 1e-9))
         self.stats.seconds += total
@@ -369,8 +370,9 @@ class Executor:
             for i in indices:
                 if i in prog.results:
                     continue
-                mode = faults.fire("exec.unit", op=node.op)  # may raise / sleep
-                result = units[i].fn()
+                with obs.span("exec.unit", unit=i):
+                    mode = faults.fire("exec.unit", op=node.op)  # may raise / sleep
+                    result = units[i].fn()
                 if mode == "corrupt":
                     result = faults.corrupt(result)
                 self.clock.advance(units[i].cost_s)
@@ -470,7 +472,8 @@ class Executor:
                     self.stats.units_preempted_lost += len(batch)
                     raise Preempted(node.label)
                 mode = faults.fire("exec.unit", op=node.op)  # may raise / sleep
-                finish(batch, batch.dispatch(), mode)
+                with obs.span("exec.batch", units=len(batch)):
+                    finish(batch, batch.dispatch(), mode)
                 self.clock.advance(batch.cost_s)
                 spent += batch.cost_s
             return spent
@@ -485,7 +488,8 @@ class Executor:
                 if preempt_check is not None and preempt_check():
                     raise Preempted(node.label)
                 mode = faults.fire("exec.unit", op=node.op)  # may raise / sleep
-                handle = batch.dispatch()
+                with obs.span("exec.batch", units=len(batch)):
+                    handle = batch.dispatch()
                 if inflight is not None:
                     finish(*inflight)
                 inflight = (batch, handle, mode)

@@ -46,9 +46,11 @@ from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..core import faults as _faults
 from ..kernels import ops
 from . import blocking as B
@@ -132,6 +134,32 @@ def _kernel(backend: str):
     with foreground interactions, so a process-global save/restore would race
     (and could strand the global override in the wrong state)."""
     return ops.local_backend(backend)
+
+
+def _call(family: str, bk: str, parts: Sequence[Partition]) -> obs.span:
+    """The enqueue of one kernel call over ``parts`` (host work until the
+    device has it), as a ``dispatch.call`` span."""
+    return obs.span("dispatch.call", family=family, backend=bk,
+                    rows=sum(p.nrows for p in parts),
+                    bucket=ops.pad_len(parts[0].nrows))
+
+
+def _readback(dev, dtype=None):
+    """Device result (an array or a tuple of arrays) → host numpy, as a
+    ``dispatch.readback`` span.  Where nothing waited for the device first,
+    the copy waits for it too."""
+    arrays = dev if isinstance(dev, tuple) else (dev,)
+    with obs.span("dispatch.readback", bytes=sum(a.nbytes for a in arrays)):
+        out = tuple(np.asarray(a, dtype) for a in arrays)
+    return out if isinstance(dev, tuple) else out[0]
+
+
+def _fetch(dev, dtype=None):
+    """A synchronous kernel result on the host: a ``dispatch.wait`` span for
+    the device to finish, then the ``dispatch.readback`` copy."""
+    with obs.span("dispatch.wait"):
+        jax.block_until_ready(dev)
+    return _readback(dev, dtype)
 
 
 # --------------------------------------------------------------------------- #
@@ -414,7 +442,7 @@ def _breaker_watch(op: str, bk: str):
 def _dev_f32(col: Column):
     dev = col.__dict__.get("_dev_f32")
     if dev is None:
-        dev = jnp.asarray(np.asarray(col.data, np.float32))
+        dev = ops.upload(col.data, np.float32)
         col.__dict__["_dev_f32"] = dev
     return dev
 
@@ -422,7 +450,7 @@ def _dev_f32(col: Column):
 def _dev_i32(col: Column):
     dev = col.__dict__.get("_dev_i32")
     if dev is None:
-        dev = jnp.asarray(np.asarray(col.data, np.int32))
+        dev = ops.upload(col.data, np.int32)
         col.__dict__["_dev_i32"] = dev
     return dev
 
@@ -430,7 +458,9 @@ def _dev_i32(col: Column):
 def _dev_valid(col: Column):
     dev = col.__dict__.get("_dev_valid")
     if dev is None:
-        dev = jnp.asarray(np.asarray(col.valid_mask()))
+        with obs.span("dispatch.prep"):
+            valid = col.valid_mask()
+        dev = ops.upload(valid)
         col.__dict__["_dev_valid"] = dev
     return dev
 
@@ -508,10 +538,11 @@ def partial_stats(
         return B.partial_stats(part, cols)
 
     def _run():
-        xs, ms = _dev_stats_stack(part, names)
-        with _kernel(bk):
-            raw = np.asarray(ops.masked_stats_batch(xs, ms), np.float64)
-        return _stats_from_raw(names, raw)
+        with obs.span("dispatch.prep"):
+            xs, ms = _dev_stats_stack(part, names)
+        with _call("stats", bk, [part]), _kernel(bk):
+            raw = ops.masked_stats_batch(xs, ms)
+        return _stats_from_raw(names, _fetch(raw, np.float64))
 
     return _guarded("stats", bk, _run, lambda: B.partial_stats(part, cols))
 
@@ -610,13 +641,15 @@ def partial_groupby(
     nb = len(key_col.dictionary)
 
     def _run():
-        keys, values, valids, modes, valid_idx, agg_plan = _groupby_plan(
-            part, by, aggs
-        )
-        with _kernel(bk):
+        with obs.span("dispatch.prep"):
+            keys, values, valids, modes, valid_idx, agg_plan = _groupby_plan(
+                part, by, aggs
+            )
+        with _call("groupby", bk, [part]), _kernel(bk):
             reds, cnts = ops.segment_reduce_batch(
                 keys, values, valids, nb, modes, valid_idx
             )
+        reds, cnts = _fetch((reds, cnts))
         return _groupby_from_raw(key_col.data.dtype, agg_plan, reds, cnts)
 
     return _guarded(
@@ -640,11 +673,13 @@ def partial_value_counts(
         return B.partial_value_counts(part, col)
 
     def _run():
-        with _kernel(bk):
+        with obs.span("dispatch.prep"):
+            keys, valid = _dev_i32(c), _dev_valid(c)
+        with _call("value_counts", bk, [part]), _kernel(bk):
             _, cnts = ops.segment_reduce_batch(
-                _dev_i32(c), [], [_dev_valid(c)], len(c.dictionary), [], []
+                keys, [], [valid], len(c.dictionary), [], []
             )
-        return _vc_from_raw(c.data.dtype, np.asarray(cnts)[0])
+        return _vc_from_raw(c.data.dtype, _fetch(cnts)[0])
 
     return _guarded(
         "value_counts", bk, _run, lambda: B.partial_value_counts(part, col)
@@ -731,14 +766,16 @@ def _partial_sort_full(
     the exactly-split f64 keys — bit-for-bit the numpy stable argsort,
     including null-last ordering and ties (dictionary codes sort string
     columns, since `from_pydict` dictionaries are sorted)."""
-    keys = _sort_keys(key_col, ascending)
-    if not _sort_keys_exact(keys):
+    with obs.span("dispatch.prep"):
+        keys = _sort_keys(key_col, ascending)
+        exact = _sort_keys_exact(keys)
+    if not exact:
         return B.partial_sort(part, by, ascending, None, n_samples)
 
     def _run():
-        with _kernel(bk):
-            order = np.asarray(ops.argsort_f64(keys if ascending else -keys))
-        return _sorted_result(part, keys, order, n_samples)
+        with _call("sort", bk, [part]), _kernel(bk):
+            order = ops.argsort_f64(keys if ascending else -keys)
+        return _sorted_result(part, keys, _fetch(order), n_samples)
 
     return _guarded(
         "sort", bk, _run, lambda: B.partial_sort(part, by, ascending, None, n_samples)
@@ -756,17 +793,20 @@ def _partial_sort_limit(
 ) -> Tuple[Partition, np.ndarray]:
     if not (1 <= limit <= TOPK_MAX_K) or key_col.is_string or part.nrows <= limit:
         return B.partial_sort(part, by, ascending, limit, n_samples)
-    keys = _sort_keys(key_col, ascending)
-    if np.isnan(keys).any():
+    with obs.span("dispatch.prep"):
+        keys = _sort_keys(key_col, ascending)
+        has_nan = np.isnan(keys).any()
+        kf32 = keys.astype(np.float32)
+    if has_nan:
         # unmasked NaN keys (e.g. a merge_groupby mean output): lax.top_k
         # treats NaN as maximal and would poison the threshold, silently
         # dropping valid rows — numpy's argsort-NaN-last semantics instead
         return B.partial_sort(part, by, ascending, limit, n_samples)
-    kf32 = keys.astype(np.float32)
 
     def _run():
-        with _kernel(bk):
-            winners = np.asarray(ops.topk_padded(kf32, limit, largest=not ascending))
+        with _call("topk", bk, [part]), _kernel(bk):
+            winners = ops.topk_padded(kf32, limit, largest=not ascending)
+        winners = _fetch(winners)
         return _limit_select(part, keys, kf32, winners, ascending, limit, n_samples)
 
     return _guarded(
@@ -845,9 +885,9 @@ def merge_sort(
             if not slices:
                 continue
             chunk = PTable(slices).concat()
-            with _kernel(bk):
-                order = np.asarray(ops.argsort_f64(np.concatenate(skeys)))
-            out_parts.append(chunk.take(order))
+            with _call("merge_sort", bk, [chunk]), _kernel(bk):
+                order = ops.argsort_f64(np.concatenate(skeys))
+            out_parts.append(chunk.take(_fetch(order)))
         return PTable(out_parts or [parts[0].slice(0, 0)])
 
     return _guarded(
@@ -903,7 +943,7 @@ def _join_build_cached(right: "PTable", on: str):
     if not _join_keys_exact(rmerged.columns[on]):
         entry = None
     else:
-        entry = (rmerged, r_sorted, r_order, jnp.asarray(r_sorted.astype(np.float32)))
+        entry = (rmerged, r_sorted, r_order, ops.upload(r_sorted, np.float32))
     cache[on] = entry
     return entry
 
@@ -935,7 +975,7 @@ def join_partition(
             rmerged_s, sb = sharded
 
             def _run_sharded():
-                gather, hit = dist.join_probe(sb, np.asarray(_dev_f32(lcol)))
+                gather, hit = dist.join_probe(sb, _fetch(_dev_f32(lcol)))
                 if lcol.mask is not None:
                     hit = hit & np.asarray(lcol.mask)  # null left keys never match
                 return B.join_assemble(left, rmerged_s, gather, hit, how, on)
@@ -957,10 +997,12 @@ def join_partition(
         return B.join_assemble(left, rmerged, gather, hit, how, on)
 
     def _run():
-        with _kernel(bk):
-            pos, hit_dev = ops.join_probe_padded(r_dev, _dev_f32(lcol))
-        hit = np.asarray(hit_dev)
-        gather = r_order[np.asarray(pos)]
+        with obs.span("dispatch.prep"):
+            lkeys = _dev_f32(lcol)
+        with _call("join", bk, [left]), _kernel(bk):
+            pos, hit_dev = ops.join_probe_padded(r_dev, lkeys)
+        pos, hit = _fetch((pos, hit_dev))
+        gather = r_order[pos]
         if lcol.mask is not None:
             hit = hit & np.asarray(lcol.mask)  # null left keys never match
         return B.join_assemble(left, rmerged, gather, hit, how, on)
@@ -1035,14 +1077,15 @@ def plan_stats_batch(
 
     def dispatch():
         with _breaker_watch("stats", bk):
-            stacks = [_dev_stats_stack(p, names) for p in parts]
-            with _kernel(bk):
+            with obs.span("dispatch.prep"):
+                stacks = [_dev_stats_stack(p, names) for p in parts]
+            with _call("stats", bk, parts), _kernel(bk):
                 return ops.masked_stats_batch_parts(
                     [xs for xs, _ in stacks], [ms for _, ms in stacks]
                 )
 
     def finalize(raw):
-        raw = np.asarray(raw, np.float64)
+        raw = _readback(raw, np.float64)
         return [
             _stats_from_raw(names, raw[i * C:(i + 1) * C])
             for i in range(len(parts))
@@ -1066,7 +1109,8 @@ def plan_groupby_batch(
     if any(not _groupby_supported(p, by, aggs, topk_keys) for p in parts):
         return None
     nb = len(parts[0].columns[by].dictionary)
-    plans = [_groupby_plan(p, by, aggs) for p in parts]
+    with obs.span("dispatch.prep"):
+        plans = [_groupby_plan(p, by, aggs) for p in parts]
     _, _, valids0, modes0, vidx0, aplan0 = plans[0]
     for pl in plans[1:]:
         # the fused call shares one (modes, valid_idx) trace: partitions whose
@@ -1079,7 +1123,7 @@ def plan_groupby_batch(
 
     def dispatch():
         with _breaker_watch("groupby", bk):
-            with _kernel(bk):
+            with _call("groupby", bk, parts), _kernel(bk):
                 return ops.segment_reduce_batch_parts(
                     [pl[0] for pl in plans],
                     [pl[1] for pl in plans],
@@ -1088,9 +1132,7 @@ def plan_groupby_batch(
                 )
 
     def finalize(handle):
-        reds, cnts = handle
-        reds = np.asarray(reds)
-        cnts = np.asarray(cnts)
+        reds, cnts = _readback(tuple(handle))
         return [
             _groupby_from_raw(
                 parts[i].columns[by].data.dtype, plans[i][5], reds[i], cnts[i]
@@ -1115,17 +1157,16 @@ def plan_value_counts_batch(
 
     def dispatch():
         with _breaker_watch("value_counts", bk):
-            with _kernel(bk):
+            with obs.span("dispatch.prep"):
+                keys = [_dev_i32(p.columns[col]) for p in parts]
+                valids = [[_dev_valid(p.columns[col])] for p in parts]
+            with _call("value_counts", bk, parts), _kernel(bk):
                 return ops.segment_reduce_batch_parts(
-                    [_dev_i32(p.columns[col]) for p in parts],
-                    [[] for _ in parts],
-                    [[_dev_valid(p.columns[col])] for p in parts],
-                    nb, [], [],
+                    keys, [[] for _ in parts], valids, nb, [], [],
                 )
 
     def finalize(handle):
-        _, cnts = handle
-        cnts = np.asarray(cnts)
+        cnts = _readback(handle[1])
         return [
             _vc_from_raw(parts[i].columns[col].data.dtype, cnts[i][0])
             for i in range(len(parts))
@@ -1150,19 +1191,21 @@ def plan_sort_batch(
     if limit is None:
         if not _BOARD.is_closed("sort", bk):
             return None
-        keys_list = [_sort_keys(p.columns[by], ascending) for p in parts]
-        if not all(_sort_keys_exact(k) for k in keys_list):
+        with obs.span("dispatch.prep"):
+            keys_list = [_sort_keys(p.columns[by], ascending) for p in parts]
+            exact = all(_sort_keys_exact(k) for k in keys_list)
+        if not exact:
             return None
 
         def dispatch():
             with _breaker_watch("sort", bk):
-                with _kernel(bk):
+                with _call("sort", bk, parts), _kernel(bk):
                     return ops.argsort_f64_parts(
                         [k if ascending else -k for k in keys_list]
                     )
 
         def finalize(handle):
-            orders = np.asarray(handle)
+            orders = _readback(handle)
             return [
                 _sorted_result(
                     parts[i], keys_list[i], orders[i][: parts[i].nrows], n_samples
@@ -1180,18 +1223,20 @@ def plan_sort_batch(
         p.columns[by].is_string or p.nrows <= limit for p in parts
     ):
         return None
-    keys_list = [_sort_keys(p.columns[by], ascending) for p in parts]
-    if any(np.isnan(k).any() for k in keys_list):
+    with obs.span("dispatch.prep"):
+        keys_list = [_sort_keys(p.columns[by], ascending) for p in parts]
+        has_nan = any(np.isnan(k).any() for k in keys_list)
+        kf32s = [k.astype(np.float32) for k in keys_list]
+    if has_nan:
         return None  # NaN keys poison lax.top_k thresholds (see unbatched path)
-    kf32s = [k.astype(np.float32) for k in keys_list]
 
     def dispatch():
         with _breaker_watch("topk", bk):
-            with _kernel(bk):
+            with _call("topk", bk, parts), _kernel(bk):
                 return ops.topk_padded_parts(kf32s, limit, largest=not ascending)
 
     def finalize(handle):
-        winners = np.asarray(handle)
+        winners = _readback(handle)
         return [
             _limit_select(
                 parts[i], keys_list[i], kf32s[i], winners[i],
@@ -1222,32 +1267,33 @@ def plan_select_rows_batch(
 
     def dispatch():
         with _breaker_watch("filter", bk):
-            keeps = [np.asarray(k, bool) for k in keeps_fn()]
-            xs_rows: list = []
-            keeps_rows: list = []
-            row_of: Dict[Tuple[int, str, str], int] = {}
-            for i, (p, keep) in enumerate(zip(parts, keeps)):
-                keep_dev = jnp.asarray(keep)
-                for name in p.order:
-                    c = p.columns[name]
-                    if not _compact_lossless(c):
-                        continue
-                    row_of[(i, name, "data")] = len(xs_rows)
-                    xs_rows.append(_dev_f32(c))
-                    keeps_rows.append(keep_dev)
-                    if c.mask is not None:
-                        row_of[(i, name, "mask")] = len(xs_rows)
-                        xs_rows.append(jnp.asarray(c.mask).astype(jnp.float32))
+            with obs.span("dispatch.prep"):
+                keeps = [np.asarray(k, bool) for k in keeps_fn()]
+                xs_rows: list = []
+                keeps_rows: list = []
+                row_of: Dict[Tuple[int, str, str], int] = {}
+                for i, (p, keep) in enumerate(zip(parts, keeps)):
+                    keep_dev = ops.upload(keep)
+                    for name in p.order:
+                        c = p.columns[name]
+                        if not _compact_lossless(c):
+                            continue
+                        row_of[(i, name, "data")] = len(xs_rows)
+                        xs_rows.append(_dev_f32(c))
                         keeps_rows.append(keep_dev)
+                        if c.mask is not None:
+                            row_of[(i, name, "mask")] = len(xs_rows)
+                            xs_rows.append(ops.upload(c.mask).astype(jnp.float32))
+                            keeps_rows.append(keep_dev)
             out = None
             if xs_rows:
-                with _kernel(bk):
+                with _call("filter", bk, parts), _kernel(bk):
                     out, _ = ops.filter_compact_padded_parts(xs_rows, keeps_rows)
             return keeps, row_of, out
 
     def finalize(handle):
         keeps, row_of, out = handle
-        out = np.asarray(out) if out is not None else None
+        out = _readback(out) if out is not None else None
         results = []
         for i, p in enumerate(parts):
             keep = keeps[i]
@@ -1282,24 +1328,31 @@ def select_rows(
         count = int(keep.sum())
         # upload + pad the keep mask once; column data rides the device cache
         nb = ops.pad_len(part.nrows)
-        keep_dev = jnp.asarray(keep)
+        keep_dev = ops.upload(keep)
         if nb != part.nrows:
-            keep_dev = jnp.pad(keep_dev, (0, nb - part.nrows), constant_values=False)
+            with obs.span("dispatch.prep"):
+                keep_dev = jnp.pad(
+                    keep_dev, (0, nb - part.nrows), constant_values=False
+                )
         new_cols: Dict[str, Column] = {}
         with _kernel(bk):
             for name in part.order:
                 c = part.columns[name]
                 if not _compact_lossless(c):
-                    new_cols[name] = c.select(keep)
+                    with obs.span("dispatch.prep"):
+                        new_cols[name] = c.select(keep)
                     continue
-                out, _ = ops.filter_compact_padded(_dev_f32(c), keep_dev)
-                data = np.asarray(out)[:count].astype(c.data.dtype)
+                x = _dev_f32(c)
+                with _call("filter", bk, [part]):
+                    out, _ = ops.filter_compact_padded(x, keep_dev)
+                data = _fetch(out)[:count].astype(c.data.dtype)
                 mask = None
                 if c.mask is not None:
-                    mout, _ = ops.filter_compact_padded(
-                        jnp.asarray(c.mask).astype(jnp.float32), keep_dev
-                    )
-                    mask = np.asarray(mout)[:count] > 0.5
+                    with obs.span("dispatch.prep"):
+                        m = ops.upload(c.mask).astype(jnp.float32)
+                    with _call("filter", bk, [part]):
+                        mout, _ = ops.filter_compact_padded(m, keep_dev)
+                    mask = _fetch(mout)[:count] > 0.5
                 new_cols[name] = Column(data=data, mask=mask, dictionary=c.dictionary)
         return Partition(new_cols, list(part.order))
 
@@ -1340,12 +1393,11 @@ def fused_stats_partition(
         return None
 
     def _run():
-        xs, ms = _dev_stats_stack(part, names)
-        with _kernel(bk):
-            raw = np.asarray(
-                ops.filter_then_masked_stats(xs, ms, keep), np.float64
-            )
-        return _stats_from_raw(names, raw)
+        with obs.span("dispatch.prep"):
+            xs, ms = _dev_stats_stack(part, names)
+        with _call("fused_stats", bk, [part]), _kernel(bk):
+            raw = ops.filter_then_masked_stats(xs, ms, keep)
+        return _stats_from_raw(names, _fetch(raw, np.float64))
 
     return _guarded("fused_stats", bk, _run, lambda: None)
 
@@ -1407,13 +1459,15 @@ def fused_groupby_partition(
         return None
 
     def _run():
-        keys, values, valids, modes, valid_idx, agg_plan = _fused_groupby_plan(
-            part, by, aggs
-        )
-        with _kernel(bk):
+        with obs.span("dispatch.prep"):
+            keys, values, valids, modes, valid_idx, agg_plan = _fused_groupby_plan(
+                part, by, aggs
+            )
+        with _call("fused_groupby", bk, [part]), _kernel(bk):
             reds, cnts = ops.filter_then_segment_reduce(
                 keys, values, valids, keep, nb, modes, valid_idx
             )
+        reds, cnts = _fetch((reds, cnts))
         return _groupby_from_raw(key_col.data.dtype, agg_plan, reds, cnts)
 
     return _guarded("fused_groupby", bk, _run, lambda: None)
@@ -1448,10 +1502,9 @@ def fused_topk_partition(
     kf32 = keys.astype(np.float32)
 
     def _run():
-        with _kernel(bk):
-            winners = np.asarray(
-                ops.topk_masked_padded(kf32, keep, limit, largest=not ascending)
-            )
+        with _call("fused_topk", bk, [part]), _kernel(bk):
+            winners = ops.topk_masked_padded(kf32, keep, limit, largest=not ascending)
+        winners = _fetch(winners)
         kth = winners[-1]
         kk32 = kf32[kept_idx]
         cand = np.nonzero(kk32 <= kth if ascending else kk32 >= kth)[0]

@@ -12,6 +12,7 @@ from typing import Any, Callable, List, Optional, Sequence
 
 import numpy as np
 
+from .. import obs
 from ..core.costmodel import CostModel
 from ..core.dag import Node
 from ..core.engine import Engine
@@ -76,23 +77,23 @@ class FrameRuntime:
 
     def _timed(self, node: Node, rows: int, fn: Callable[[str], Any]) -> Callable[[], Any]:
         """Wrap a partial-unit body: resolve the backend (planner included)
-        at execution time, measure wall time, and feed the sample to
-        cost-model calibration under the node's *planning key* — so the
-        samples keep refining exactly the estimates the planner consults.
-        The sample is labelled with the backend that actually *served* the
-        dispatch — when the runtime guard falls back to numpy (kernel error,
-        open breaker) the time must calibrate the numpy path, or a single
-        kernel failure would permanently skew the kernel's fitted cost."""
+        at execution time, time the body as a ``dispatch.partial`` span, and
+        feed the span's duration to cost-model calibration under the node's
+        *planning key* — so the samples keep refining exactly the estimates
+        the planner consults.  The sample is labelled with the backend that
+        actually *served* the dispatch — when the runtime guard falls back to
+        numpy (kernel error, open breaker) the time must calibrate the numpy
+        path, or a single kernel failure would permanently skew the kernel's
+        fitted cost."""
         key = planner_key(node)
 
         def run():
             bk = self._planned_backend(key, rows)
             BK.note_reset()
-            t0 = time.perf_counter()
-            out = fn(bk)
-            dt = time.perf_counter() - t0
+            with obs.span("dispatch.partial", rows=rows) as sp:
+                out = fn(bk)
             served, _reason = BK.served_backend(bk)
-            self.cost_model.add_sample(key, served, rows, dt)
+            self.cost_model.add_sample(key, served, rows, sp.ns * 1e-9)
             return out
 
         return run
@@ -380,7 +381,8 @@ class FrameRuntime:
             return node.kwargs["expr"]
 
         def filter_apply(node: Node, part: Partition, extras) -> Partition:
-            keep = predicate_mask(filter_expr(node), part, extras)
+            with obs.span("dispatch.prep"):
+                keep = predicate_mask(filter_expr(node), part, extras)
             return self._timed(
                 node, part.nrows, lambda bk: BK.select_rows(part, keep, backend=bk)
             )()
@@ -420,7 +422,8 @@ class FrameRuntime:
             return keep
 
         def dropna_apply(node: Node, part: Partition, extras) -> Partition:
-            keep = dropna_keep(node, part)
+            with obs.span("dispatch.prep"):
+                keep = dropna_keep(node, part)
             return self._timed(
                 node, part.nrows, lambda bk: BK.select_rows(part, keep, backend=bk)
             )()

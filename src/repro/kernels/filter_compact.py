@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import obs
 from .lanes import LANES, row_layout, to_lanes
 
 DEFAULT_TILE_ROWS = 64
@@ -44,6 +45,7 @@ def _compact_kernel(x_ref, keep_ref, out_ref, *, fill: float):
 
 
 @functools.partial(jax.jit, static_argnames=("tile_rows", "fill", "interpret"))
+@obs.device_scope("filter_compact")
 def filter_compact(
     x: jnp.ndarray,  # f32[n]
     keep: jnp.ndarray,  # bool[n]

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import obs
 from .lanes import LANES, SUBLANES, row_layout, to_lanes
 
 DEFAULT_TILE_ROWS = 256  # 32K left keys per grid step
@@ -75,6 +76,7 @@ def _probe_kernel(
 @functools.partial(
     jax.jit, static_argnames=("tile_rows", "right_block", "interpret")
 )
+@obs.device_scope("join_probe")
 def join_probe(
     l_keys: jnp.ndarray,  # f32[n]
     r_sorted: jnp.ndarray,  # f32[m] ascending, unique among finite entries

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import obs
 from .lanes import LANES, SUBLANES, row_layout, tile_max, tile_min, tile_sum, to_lanes
 
 DEFAULT_TILE_ROWS = 512  # 64K elements (256 KiB of f32) per grid step
@@ -82,6 +83,7 @@ def _stats_kernel(
 
 
 @functools.partial(jax.jit, static_argnames=("tile_rows", "interpret"))
+@obs.device_scope("masked_stats")
 def masked_stats(
     x: jnp.ndarray,  # f32[n]
     mask: jnp.ndarray,  # bool[n]

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from . import ref
 from .filter_compact import filter_compact as _filter_pallas
 from .flash_attention import flash_attention as _attn_pallas
@@ -139,6 +140,25 @@ PAD_MIN = 512  # smallest padded length (also amortises tiny partitions)
 _TILE = 16384  # scan-tile rows for the CPU/XLA paths: temps stay cache-resident
 
 
+def upload(host, dtype=None) -> jnp.ndarray:
+    """A host array on the device: the dtype conversion as a
+    ``dispatch.prep`` span, the transfer as a ``dispatch.upload`` span with
+    its bytes."""
+    host = np.asarray(host)
+    if dtype is not None and host.dtype != dtype:
+        with obs.span("dispatch.prep"):
+            host = host.astype(dtype)
+    with obs.span("dispatch.upload", bytes=host.nbytes):
+        return jnp.asarray(host)
+
+
+def _device(x, dtype) -> jnp.ndarray:
+    """``jnp.asarray(x, dtype)``, through :func:`upload` for a host array."""
+    if isinstance(x, np.ndarray):
+        return upload(x, dtype)
+    return jnp.asarray(x, dtype)
+
+
 def pad_len(n: int, minimum: int = PAD_MIN) -> int:
     """Next power-of-two bucket ≥ n (≥ minimum) — the shared jit shape."""
     if n <= minimum:
@@ -201,6 +221,7 @@ def _stats_row_tiled(x: jnp.ndarray, m: jnp.ndarray, tile: int) -> jnp.ndarray:
 
 
 @functools.partial(jax.jit, static_argnames=("tile",))
+@obs.device_scope("masked_stats")
 def _masked_stats_batch_xla(xs: jnp.ndarray, ms: jnp.ndarray, tile: int) -> jnp.ndarray:
     return jnp.stack(
         [_stats_row_tiled(xs[i], ms[i], tile) for i in range(xs.shape[0])]
@@ -242,7 +263,9 @@ def _topk_body(x: jnp.ndarray, k: int, largest: bool) -> jnp.ndarray:
     return vals if largest else -vals
 
 
-_topk_xla = functools.partial(jax.jit, static_argnames=("k", "largest"))(_topk_body)
+_topk_xla = functools.partial(jax.jit, static_argnames=("k", "largest"))(
+    obs.device_scope("topk")(_topk_body)
+)
 
 
 def topk_padded(x, k: int, largest: bool = True) -> jnp.ndarray:
@@ -250,7 +273,7 @@ def topk_padded(x, k: int, largest: bool = True) -> jnp.ndarray:
 
     The xla path uses ``lax.top_k`` directly (a single O(n) selection pass —
     far cheaper than the sort-based reference oracle)."""
-    x = jnp.asarray(x, jnp.float32)
+    x = _device(x, jnp.float32)
     nb = pad_len(x.shape[0])
     sentinel = -jnp.inf if largest else jnp.inf
     xp = _pad1(x, nb, sentinel)
@@ -324,7 +347,7 @@ def _sort_order_body(hi: jnp.ndarray, mid: jnp.ndarray, lo: jnp.ndarray):
     return order
 
 
-_sort_order_xla = jax.jit(_sort_order_body)
+_sort_order_xla = jax.jit(obs.device_scope("sort")(_sort_order_body))
 
 
 def sort_order_padded(hi, mid, lo) -> jnp.ndarray:
@@ -337,12 +360,12 @@ def sort_order_padded(hi, mid, lo) -> jnp.ndarray:
     TPU-optimal implementation (the same bitonic network a hand-written
     Mosaic kernel would emit), so unlike the reduction kernels there is no
     separate Pallas path to dispatch to."""
-    hi = jnp.asarray(hi, jnp.float32)
+    hi = _device(hi, jnp.float32)
     n = hi.shape[0]
     nb = pad_len(n)
     hi = _pad1(hi, nb, jnp.inf)
-    mid = _pad1(jnp.asarray(mid, jnp.float32), nb, 0.0)
-    lo = _pad1(jnp.asarray(lo, jnp.float32), nb, 0.0)
+    mid = _pad1(_device(mid, jnp.float32), nb, 0.0)
+    lo = _pad1(_device(lo, jnp.float32), nb, 0.0)
     return _sort_order_xla(hi, mid, lo)[:n]
 
 
@@ -352,13 +375,16 @@ def argsort_f64(keys) -> jnp.ndarray:
     above): no NaN, and every key must survive the 3×f32 split exactly —
     callers gate with ``_sort_keys_exact``, which rejects overflow (|x| ≥ f32
     max) and underflow (|x| ≲ 2^-100) magnitudes."""
-    return sort_order_padded(*split_f64(keys))
+    with obs.span("dispatch.prep"):
+        parts = split_f64(keys)
+    return sort_order_padded(*parts)
 
 
 # -- sorted-lookup join probe -------------------------------------------------
 
 
 @functools.partial(jax.jit, static_argnames=("m",))
+@obs.device_scope("join_probe")
 def _join_probe_xla(r_sorted: jnp.ndarray, l_keys: jnp.ndarray, m: int):
     pos = jnp.searchsorted(r_sorted, l_keys, side="left")
     posc = jnp.clip(pos, 0, m - 1)
@@ -465,7 +491,9 @@ def _segment_batch_body(
 
 
 _segment_batch_xla = functools.partial(jax.jit, static_argnames=(
-    "num_buckets", "modes", "valid_idx", "tile"))(_segment_batch_body)
+    "num_buckets", "modes", "valid_idx", "tile"))(
+    obs.device_scope("segment_reduce")(_segment_batch_body)
+)
 
 
 def segment_reduce_batch(
@@ -553,6 +581,7 @@ def segment_reduce_batch(
 @functools.partial(
     jax.jit, static_argnames=("num_buckets", "modes", "valid_idx", "tile")
 )
+@obs.device_scope("segment_reduce")
 def _segment_parts_xla(
     keys: jnp.ndarray,  # int32[P, nb]
     values: Tuple[jnp.ndarray, ...],  # S × f32[P, nb]
@@ -630,6 +659,7 @@ def segment_reduce_batch_parts(
 
 
 @functools.partial(jax.jit, static_argnames=("k", "largest"))
+@obs.device_scope("topk")
 def _topk_parts_xla(xs: jnp.ndarray, k: int, largest: bool) -> jnp.ndarray:
     return jax.lax.map(lambda x: _topk_body(x, k, largest), xs)
 
@@ -638,11 +668,12 @@ def _stack_host_padded(rows: Sequence, nb: int, fill, dtype) -> jnp.ndarray:
     """Pad + stack *host* arrays on host, then upload once.  Stacking on
     device instead would cost one transfer per row — exactly the per-dispatch
     overhead the fused entry points exist to amortise."""
-    out = np.full((len(rows), nb), fill, dtype)
-    for i, r in enumerate(rows):
-        r = np.asarray(r, dtype)
-        out[i, : r.shape[0]] = r
-    return jnp.asarray(out)
+    with obs.span("dispatch.prep"):
+        out = np.full((len(rows), nb), fill, dtype)
+        for i, r in enumerate(rows):
+            r = np.asarray(r, dtype)
+            out[i, : r.shape[0]] = r
+    return upload(out)
 
 
 def topk_padded_parts(xs_parts: Sequence, k: int, largest: bool = True) -> jnp.ndarray:
@@ -661,6 +692,7 @@ def topk_padded_parts(xs_parts: Sequence, k: int, largest: bool = True) -> jnp.n
 
 
 @jax.jit
+@obs.device_scope("sort")
 def _sort_order_parts_xla(hi: jnp.ndarray, mid: jnp.ndarray, lo: jnp.ndarray):
     return jax.lax.map(lambda t: _sort_order_body(*t), (hi, mid, lo))
 
@@ -675,7 +707,8 @@ def argsort_f64_parts(keys_parts: Sequence) -> jnp.ndarray:
     if len(nbs) != 1:
         raise ValueError(f"partitions span shape buckets {sorted(nbs)}; group first")
     nb = nbs.pop()
-    splits = [split_f64(k) for k in keys_parts]
+    with obs.span("dispatch.prep"):
+        splits = [split_f64(k) for k in keys_parts]
     his = _stack_host_padded([s[0] for s in splits], nb, np.float32(np.inf), np.float32)
     mids = _stack_host_padded([s[1] for s in splits], nb, np.float32(0.0), np.float32)
     los = _stack_host_padded([s[2] for s in splits], nb, np.float32(0.0), np.float32)
@@ -683,6 +716,7 @@ def argsort_f64_parts(keys_parts: Sequence) -> jnp.ndarray:
 
 
 @jax.jit
+@obs.device_scope("filter_compact")
 def _filter_parts_xla(xs: jnp.ndarray, keeps: jnp.ndarray):
     return jax.lax.map(lambda t: ref.filter_compact_ref(t[0], t[1], 0.0), (xs, keeps))
 
@@ -713,6 +747,7 @@ def filter_compact_padded_parts(
 
 
 @functools.partial(jax.jit, static_argnames=("tile",))
+@obs.device_scope("masked_stats")
 def _masked_stats_rows_map_xla(xs: jnp.ndarray, ms: jnp.ndarray, tile: int):
     return jax.lax.map(lambda t: _stats_row_tiled(t[0], t[1], tile), (xs, ms))
 
@@ -776,6 +811,7 @@ def _compact_gather_idx(keep, nb: int) -> np.ndarray:
 
 
 @functools.partial(jax.jit, static_argnames=("tile",))
+@obs.device_scope("filter_then_masked_stats")
 def _filter_stats_xla(
     xs: jnp.ndarray, ms: jnp.ndarray, idx: jnp.ndarray, tile: int
 ) -> jnp.ndarray:
@@ -803,15 +839,16 @@ def filter_then_masked_stats(xs, ms, keep) -> jnp.ndarray:
     nb = pad_len(n)
     if backend() == "xla":
         nb = max(nb, _TILE)
-    idx = _compact_gather_idx(keep, nb)
+    with obs.span("dispatch.prep"):
+        idx = _compact_gather_idx(keep, nb)
     if nb != n:
         xs = jnp.pad(xs, ((0, 0), (0, nb - n)))
         ms = jnp.pad(ms, ((0, 0), (0, nb - n)), constant_values=False)
     if backend() == "xla":
-        return _filter_stats_xla(xs, ms, jnp.asarray(idx), _TILE)
+        return _filter_stats_xla(xs, ms, upload(idx), _TILE)
     # interpret / pallas: compact via the reference scatter math, reduce via
     # the backend's own stats path (correctness-only backends here)
-    keep_dev = _pad1(jnp.asarray(np.asarray(keep, bool)), nb, False)
+    keep_dev = _pad1(upload(keep, bool), nb, False)
     rows = []
     for i in range(c):
         xc, _ = ref.filter_compact_ref(xs[i], keep_dev, 0.0)
@@ -825,6 +862,7 @@ def filter_then_masked_stats(xs, ms, keep) -> jnp.ndarray:
 @functools.partial(
     jax.jit, static_argnames=("num_buckets", "modes", "valid_idx", "tile")
 )
+@obs.device_scope("filter_then_segment_reduce")
 def _filter_segment_xla(
     keys: jnp.ndarray,  # i32[n] group codes
     values: Tuple[jnp.ndarray, ...],
@@ -868,16 +906,17 @@ def filter_then_segment_reduce(
     nb = pad_len(n)
     if backend() == "xla":
         nb = max(nb, _TILE)
-    idx = _compact_gather_idx(keep, nb)
+    with obs.span("dispatch.prep"):
+        idx = _compact_gather_idx(keep, nb)
     keys = _pad1(keys, nb, 0)
     values = tuple(_pad1(jnp.asarray(v, jnp.float32), nb, 0.0) for v in values)
     valids = tuple(_pad1(jnp.asarray(m, bool), nb, False) for m in valids)
     if backend() == "xla":
         return _filter_segment_xla(
-            keys, values, valids, jnp.asarray(idx), int(num_buckets),
+            keys, values, valids, upload(idx), int(num_buckets),
             tuple(modes), tuple(int(i) for i in valid_idx), _TILE,
         )
-    keep_dev = _pad1(jnp.asarray(np.asarray(keep, bool)), nb, False)
+    keep_dev = _pad1(upload(keep, bool), nb, False)
     keys_c = ref.filter_compact_ref(keys.astype(jnp.float32), keep_dev, 0.0)[0]
     vals_c = [ref.filter_compact_ref(v, keep_dev, 0.0)[0] for v in values]
     mins_c = [
@@ -890,6 +929,7 @@ def filter_then_segment_reduce(
 
 
 @functools.partial(jax.jit, static_argnames=("k", "largest"))
+@obs.device_scope("filter_then_topk")
 def _topk_masked_xla(
     x: jnp.ndarray, keep: jnp.ndarray, k: int, largest: bool
 ) -> jnp.ndarray:
@@ -903,8 +943,8 @@ def topk_masked_padded(x, keep, k: int, largest: bool = True) -> jnp.ndarray:
     jit.  ``lax.top_k`` returns *values*, so the result equals
     ``topk_padded`` on the compacted kept rows exactly (same value multiset,
     sentinels lose; callers gate kept-count > k so no sentinel wins)."""
-    x = jnp.asarray(x, jnp.float32)
-    keep = jnp.asarray(keep, bool)
+    x = _device(x, jnp.float32)
+    keep = _device(keep, bool)
     nb = pad_len(x.shape[0])
     sentinel = -jnp.inf if largest else jnp.inf
     xp = _pad1(x, nb, sentinel)

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import obs
 from .lanes import LANES, SUBLANES, row_layout, tile_max, tile_min, tile_sum, to_lanes
 
 DEFAULT_TILE_ROWS = 256  # 32K rows per grid step
@@ -72,6 +73,7 @@ def _segment_kernel(
 @functools.partial(
     jax.jit, static_argnames=("num_buckets", "mode", "tile_rows", "interpret"),
 )
+@obs.device_scope("segment_reduce")
 def segment_reduce(
     keys: jnp.ndarray,  # int32[n]
     values: jnp.ndarray,  # f32[n]

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import obs
 from .lanes import LANES, SUBLANES, row_layout, tile_max, tile_min, to_lanes
 
 DEFAULT_TILE_ROWS = 512  # 64K candidates per grid step
@@ -50,6 +51,7 @@ def _topk_kernel(x_ref, out_ref, vals_scr, *, k: int):
 @functools.partial(
     jax.jit, static_argnames=("k", "largest", "tile_rows", "interpret")
 )
+@obs.device_scope("topk")
 def topk(
     x: jnp.ndarray,  # f32[n]
     k: int,

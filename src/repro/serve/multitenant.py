@@ -40,6 +40,7 @@ import json
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
 
+from .. import obs
 from ..core.cse import intern_program
 from ..core.dag import DAG, Node
 from ..core.engine import Engine
@@ -156,18 +157,21 @@ class MultiTenantServer:
 
         Every node of the program's closure is hash-consed against the shared
         DAG (cross-tenant CSE), subscribed to the tenant in the cache, and
-        added to the tenant's scheduler demand set."""
-        if tenant not in self._demand:
-            self.register(tenant)
-        mapping, n_new = intern_program(
-            self.engine.dag, list(roots),
-            observer=self.engine.observe_interned_node,
-        )
-        demand = self._demand.setdefault(tenant, set())
-        for shared in mapping.values():
-            self.engine.cache.subscribe(shared.nid, tenant)
-            demand.add(shared.nid)
-        self.engine.scheduler.set_tenant_demand(tenant, demand)
+        added to the tenant's scheduler demand set — under the engine's
+        lock, since the background worker reads the DAG and the scheduler's
+        per-tenant memo while it picks."""
+        eng = self.engine
+        with obs.span("serve.submit"), eng._lock:
+            if tenant not in self._demand:
+                self.register(tenant)
+            mapping, n_new = intern_program(
+                eng.dag, list(roots), observer=eng.observe_interned_node,
+            )
+            demand = self._demand.setdefault(tenant, set())
+            for shared in mapping.values():
+                eng.cache.subscribe(shared.nid, tenant)
+                demand.add(shared.nid)
+            eng.scheduler.set_tenant_demand(tenant, demand)
         prog = TenantProgram(
             tenant=tenant,
             roots=[mapping[r.nid] for r in roots],

@@ -320,3 +320,60 @@ def test_submit_feeds_predictor_and_speculation():
     _, root3 = synthetic_trace_program(2, 1)
     srv.submit("alice", [root3])
     assert transitions() == 6  # source deduped, 3 new stage nodes observed
+
+
+# ------------------------------------------------- submit against the worker --
+def test_submit_races_no_background_pick():
+    """The background worker picks under the engine's lock while analysts
+    submit: ``submit`` interns into the shared DAG and edits the scheduler's
+    per-tenant memo, so it takes the same lock (a pick filling the memo
+    during ``set_tenant_demand``'s scan raised "dictionary changed size
+    during iteration")."""
+    import sys
+    import threading
+    import time
+
+    eng = _engine()
+    srv = MultiTenantServer(eng)
+    errors: list = []
+    done = threading.Event()
+    counts = {"picks": 0, "submits": 0}
+
+    def picker():
+        try:
+            while not done.is_set():
+                with eng._lock:
+                    eng.scheduler.pick(eng.cache.executed_ids(), now=0.0)
+                counts["picks"] += 1
+        except Exception as exc:  # recorded for the assertion below
+            errors.append(exc)
+
+    def submitter():
+        try:
+            i = 0
+            while not done.is_set():
+                # a source of its own per program: the memo grows with them
+                d = DAG()
+                src = d.add("synthetic", kwargs={"tag": f"s{i}", "cost_s": 0.1})
+                root = d.add("synthetic", parents=[src], kwargs={"tag": f"r{i}"})
+                srv.submit(f"t{i % 24}", [root])
+                counts["submits"] += 1
+                i += 1
+        except Exception as exc:  # recorded for the assertion below
+            errors.append(exc)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=picker), threading.Thread(target=submitter)]
+        for t in threads:
+            t.start()
+        time.sleep(1.5)
+        done.set()
+        for t in threads:
+            t.join(timeout=30)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert not errors, errors
+    assert counts["picks"] > 0 and counts["submits"] > 0
