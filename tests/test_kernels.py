@@ -105,6 +105,47 @@ def test_filter_compact_sweep(n, sel):
     np.testing.assert_allclose(np.asarray(out), np.asarray(rout), atol=1e-6)
 
 
+# 3,500 values at 8 rows of 128 lanes a tile: 28 rows of data, 4 tiles
+BITS_N, BITS_TILE = 3500, 8
+SPECIALS = np.array([0x7FC01234, 0xFFC00ABC, 0x7F800000, 0xFF800000, 0x80000000],
+                    np.uint32).view(np.float32)  # NaNs with payloads, ±inf, −0.0
+
+
+KEEP_CASES = ("sel0", "sel1", "sel0.03", "sel0.5", "one_per_tile",
+              "last_row_only", "runs")
+
+
+def _keep_case(case: str) -> np.ndarray:
+    rng = np.random.default_rng(KEEP_CASES.index(case))
+    keep = np.zeros(BITS_N, bool)
+    if case in ("sel0", "sel1", "sel0.03", "sel0.5"):
+        keep = rng.uniform(size=BITS_N) < float(case[3:])
+    elif case == "one_per_tile":
+        keep[np.arange(4) * BITS_TILE * 128 + 300] = True
+    elif case == "last_row_only":
+        keep[27 * 128:][rng.uniform(size=BITS_N - 27 * 128) < 0.5] = True
+    else:  # runs across row (256) and tile (1024, 2048) boundaries, whole rows
+        for a, b in ((250, 270), (1000, 1100), (2040, 2060), (2500, 2900)):
+            keep[a:b] = True
+    return keep
+
+
+@pytest.mark.parametrize("fill", [0.0, -7.5])
+@pytest.mark.parametrize("case", KEEP_CASES)
+def test_filter_compact_bits_match_ref(case, fill):
+    x = np.random.default_rng(7).normal(size=BITS_N).astype(np.float32)
+    x[::7] = np.resize(SPECIALS, x[::7].shape)
+    keep = _keep_case(case)
+    out, cnt = filter_compact(jnp.asarray(x), jnp.asarray(keep),
+                              tile_rows=BITS_TILE, fill=fill, interpret=True)
+    rout, rcnt = R.filter_compact_ref(jnp.asarray(x), jnp.asarray(keep), fill)
+    out = np.asarray(out)
+    assert int(cnt) == int(rcnt) == int(keep.sum())
+    np.testing.assert_array_equal(out.view(np.uint32), np.asarray(rout).view(np.uint32))
+    np.testing.assert_array_equal(out[:int(cnt)].view(np.uint32), x[keep].view(np.uint32))
+    assert (out[int(cnt):] == np.float32(fill)).all()
+
+
 # ------------------------------------------------------------------------ topk --
 @pytest.mark.parametrize("n,k", [(100, 1), (4000, 7), (4000, 64), (999, 10)])
 @pytest.mark.parametrize("largest", [True, False])

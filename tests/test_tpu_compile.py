@@ -106,6 +106,17 @@ def test_frame_kernel_lowers_for_v5e(name, chip, bucket):
     assert "tpu_custom_call" in _compiled_text(fn, *args)
 
 
+@pytest.mark.parametrize("n", [1024, "bucket"])
+def test_filter_compact_stitches_in_the_kernel_for_v5e(n, chip, bucket):
+    """One tile (1,024 values) and many (the smoke table's largest bucket):
+    the rows are placed by the kernel, with no XLA sort or scatter around
+    it."""
+    n = bucket if n == "bucket" else n
+    text = _compiled_text(filter_compact, chip((n,), jnp.float32), chip((n,), jnp.bool_))
+    assert "tpu_custom_call" in text
+    assert " sort(" not in text and " scatter(" not in text
+
+
 # the backend-dispatching entry points that wrap the kernels on the pallas
 # backend: several kernel calls in one traced program
 ENTRY_POINTS = {
