@@ -144,6 +144,7 @@ class Engine:
         fault_plan: Optional[FaultPlan] = None,  # chaos harness (None: env)
         worker_ack_timeout_s: float = 60.0,  # pause-ack stall watchdog bound
         scheduler_memo_path: Optional[str] = None,  # persist scheduler memos
+        join_dimensions: Sequence[Sequence[str]] = (),  # (table, key) pairs
     ):
         self.dag = DAG()
         self.cost_model = CostModel()
@@ -169,6 +170,9 @@ class Engine:
         # to the cheaper backend by fitted estimate, fuse eligible linear
         # chains.  The frame runtime reads this at install time.
         self.planner_enabled = planner
+        # the dimension tables of a star: the frame runtime builds each one's
+        # join index on its key when the table is read, not in the first join
+        self.join_dimensions = tuple((str(t), str(k)) for t, k in join_dimensions)
         self.opportunistic = opportunistic
         self.partial_results = partial_results
         self.registry = Registry()

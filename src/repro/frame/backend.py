@@ -14,7 +14,7 @@ frame partial             kernel
 ``partial_sort(limit=k)`` ``topk`` (threshold + small residual argsort)
 ``partial_sort`` (full)   ``argsort_f64`` (exact 3×f32 split + ``lax.sort``)
 ``merge_sort`` (full)     sample-sort range split + ``argsort_f64``
-``join_partition``        ``join_probe`` (sorted right side, counting probe)
+``join_partition``        ``join_probe`` (sorted right side, band-merge probe)
 ``select_rows``           ``filter_compact`` (per-column compaction)
 ========================  =============================================
 
@@ -53,6 +53,7 @@ import numpy as np
 from .. import obs
 from ..core import faults as _faults
 from ..kernels import ops
+from ..kernels.join_probe import band_order, band_range
 from . import blocking as B
 from .blocking import BUILTIN_AGGS, ColStats
 from .table import Column, Partition, PTable
@@ -136,12 +137,12 @@ def _kernel(backend: str):
     return ops.local_backend(backend)
 
 
-def _call(family: str, bk: str, parts: Sequence[Partition]) -> obs.span:
+def _call(family: str, bk: str, parts: Sequence[Partition], **attrs: int) -> obs.span:
     """The enqueue of one kernel call over ``parts`` (host work until the
     device has it), as a ``dispatch.call`` span."""
     return obs.span("dispatch.call", family=family, backend=bk,
                     rows=sum(p.nrows for p in parts),
-                    bucket=ops.pad_len(parts[0].nrows))
+                    bucket=ops.pad_len(parts[0].nrows), **attrs)
 
 
 def _readback(dev, dtype=None):
@@ -445,6 +446,20 @@ def _dev_f32(col: Column):
         dev = ops.upload(col.data, np.float32)
         col.__dict__["_dev_f32"] = dev
     return dev
+
+
+def _dev_probe_keys(col: Column, bands: Tuple[float, float]):
+    """``(perm, keys)``: a join key column's band order over the range
+    ``bands`` (``kernels/join_probe.py:band_order``) on the host, and its
+    f32 keys in that order on the device, NaN-padded to the shape bucket on
+    the host, cached like ``_dev_f32``."""
+    cache = col.__dict__.setdefault("_dev_probe", {})
+    if bands not in cache:
+        perm, keys = band_order(col.data, *bands)
+        padded = np.full(ops.pad_len(len(keys)), np.nan, np.float32)
+        padded[:len(keys)] = keys
+        cache[bands] = (perm, ops.upload(padded))
+    return cache[bands]
 
 
 def _dev_i32(col: Column):
@@ -896,7 +911,7 @@ def merge_sort(
 
 
 # --------------------------------------------------------------------------- #
-# join — sorted right side built once, device-resident; counting probe        #
+# join — sorted right side built once, device-resident; band-merge probe      #
 # --------------------------------------------------------------------------- #
 
 _JOIN_INT_EXACT = 1 << 24  # f32 integer-exact range
@@ -939,13 +954,27 @@ def _join_build_cached(right: "PTable", on: str):
     cache = right.__dict__.setdefault("_join_build", {})
     if on in cache:
         return cache[on]
-    rmerged, r_sorted, r_order = B.join_build(right, on)
-    if not _join_keys_exact(rmerged.columns[on]):
-        entry = None
-    else:
-        entry = (rmerged, r_sorted, r_order, ops.upload(r_sorted, np.float32))
+    with obs.span("join.build", right_rows=right.nrows, bytes=0) as sp:
+        rmerged, r_sorted, r_order = B.join_build(right, on)
+        if not _join_keys_exact(rmerged.columns[on]):
+            entry = None
+        else:
+            r_dev = ops.upload(r_sorted, np.float32)
+            sp.attrs["bytes"] = r_dev.nbytes
+            # one slot past the end: a probe position of m (a key above every
+            # right key, never a hit) gathers in range
+            entry = (rmerged, r_sorted, np.append(r_order, 0), r_dev)
     cache[on] = entry
     return entry
+
+
+def build_join_index(right: "PTable", on: str, backend: Optional[str] = None) -> None:
+    """Build ``right``'s join index on ``on`` ahead of its first probe, as
+    ``join_partition`` would build it there: the partition-parallel build
+    where the right side is too big to broadcast, else the sorted keys and
+    their device copy.  The numpy backend keeps no index."""
+    if active_backend(backend) != "numpy" and _sharded_join_build_cached(right, on) is None:
+        _join_build_cached(right, on)
 
 
 def join_partition(
@@ -976,9 +1005,8 @@ def join_partition(
 
             def _run_sharded():
                 gather, hit = dist.join_probe(sb, _fetch(_dev_f32(lcol)))
-                if lcol.mask is not None:
-                    hit = hit & np.asarray(lcol.mask)  # null left keys never match
-                return B.join_assemble(left, rmerged_s, gather, hit, how, on)
+                return B.join_assemble(left, rmerged_s, gather, hit, how, on,
+                                       left_mask=lcol.mask)
 
             out = _guarded("join", "sharded", _run_sharded, lambda: None)
             if out is not None:
@@ -992,20 +1020,19 @@ def join_partition(
     if len(r_sorted) == 0:
         hit = np.zeros(left.nrows, dtype=bool)
         gather = np.zeros(left.nrows, dtype=np.intp)
-        if lcol.mask is not None:
-            hit = hit & np.asarray(lcol.mask)
         return B.join_assemble(left, rmerged, gather, hit, how, on)
 
     def _run():
         with obs.span("dispatch.prep"):
-            lkeys = _dev_f32(lcol)
-        with _call("join", bk, [left]), _kernel(bk):
-            pos, hit_dev = ops.join_probe_padded(r_dev, lkeys)
-        pos, hit = _fetch((pos, hit_dev))
-        gather = r_order[pos]
-        if lcol.mask is not None:
-            hit = hit & np.asarray(lcol.mask)  # null left keys never match
-        return B.join_assemble(left, rmerged, gather, hit, how, on)
+            if bk == "xla":
+                perm, lkeys = None, _dev_f32(lcol)
+            else:
+                perm, lkeys = _dev_probe_keys(lcol, band_range(r_sorted))
+        with _call("join", bk, [left], right_rows=len(r_sorted)), _kernel(bk):
+            out = ops.join_probe_padded(r_dev, lkeys)
+        pos, hit = (a[:left.nrows] for a in _fetch(out))
+        return B.join_assemble(left, rmerged, r_order[pos], hit, how, on,
+                               perm=perm, left_mask=lcol.mask)
 
     return _guarded(
         "join", bk, _run, lambda: B.join_partition(left, right, on, how)

@@ -15,6 +15,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import obs
 from ..core.scheduler import sample_first_order
 from .table import Column, Partition, PTable
 
@@ -722,44 +723,59 @@ def join_assemble(
     hit: np.ndarray,
     how: str,
     on: str,
+    perm: Optional[np.ndarray] = None,
+    left_mask: Optional[np.ndarray] = None,
 ) -> Partition:
     """Shared tail of every join path (numpy probe and kernel probe): row
     selection plus the right-column gather.  ``gather`` holds in-range row
     indices into ``rmerged``; rows with ``hit`` False are forced to index 0 so
-    every backend assembles bit-identical partitions."""
-    if how == "inner":
-        keep = np.nonzero(hit)[0]
-        out = left.take(keep)
-        gather = gather[keep]
-        hit = hit[keep]
-    elif how == "left":
-        out = left
-    else:
-        raise ValueError(f"unsupported join how={how!r}")
-    gather = np.where(hit, gather, 0)
-    miss = ~np.asarray(hit)
-    cols = dict(out.columns)
-    order = list(out.order)
-    for name in rmerged.order:
-        if name == on:
-            continue
-        src = rmerged.columns[name]
-        if rmerged.nrows == 0:
-            # nothing to gather from: all-null columns of the output length
-            taken = Column(
-                data=np.zeros(out.nrows, dtype=src.data.dtype),
-                mask=np.zeros(out.nrows, dtype=bool),
-                dictionary=src.dictionary,
-            )
-        else:
-            taken = src.take(np.asarray(gather))
-            if how == "left":
-                mask = taken.valid_mask() & ~miss
-                taken = Column(data=taken.data, mask=mask, dictionary=taken.dictionary)
-        out_name = name if name not in cols else f"{name}_right"
-        cols[out_name] = taken
-        order.append(out_name)
-    return Partition(cols, order)
+    every backend assembles bit-identical partitions.  With ``perm`` (the
+    kernel probe's band order) entry ``i`` of ``gather`` and ``hit``
+    belongs to left row ``perm[i]``.  ``left_mask`` marks the left rows whose
+    key is valid: a null key never matches."""
+    with obs.span("join.assemble", rows=left.nrows, cols=len(rmerged.order) - 1):
+        n = left.nrows
+        if perm is not None:
+            g = np.empty(n, gather.dtype)
+            g[perm] = gather
+            h = np.empty(n, bool)
+            h[perm] = hit
+            gather, hit = g, h
+        if left_mask is not None:
+            hit = hit & np.asarray(left_mask)
+        if how == "inner":
+            if not hit.all():  # every row matched: the left columns stay as they are
+                keep = np.nonzero(hit)[0]
+                left = left.take(keep)
+                gather = gather[keep]
+                hit = hit[keep]
+        elif how != "left":
+            raise ValueError(f"unsupported join how={how!r}")
+        gather = np.where(hit, gather, 0)
+        miss = ~np.asarray(hit)
+        cols = dict(left.columns)
+        order = list(left.order)
+        for name in rmerged.order:
+            if name == on:
+                continue
+            src = rmerged.columns[name]
+            if rmerged.nrows == 0:
+                # nothing to gather from: all-null columns of the output length
+                taken = Column(
+                    data=np.zeros(left.nrows, dtype=src.data.dtype),
+                    mask=np.zeros(left.nrows, dtype=bool),
+                    dictionary=src.dictionary,
+                )
+            else:
+                taken = src.take(np.asarray(gather))
+                if how == "left":
+                    mask = taken.valid_mask() & ~miss
+                    taken = Column(data=taken.data, mask=mask,
+                                   dictionary=taken.dictionary)
+            out_name = name if name not in cols else f"{name}_right"
+            cols[out_name] = taken
+            order.append(out_name)
+        return Partition(cols, order)
 
 
 def join_partition(
@@ -774,10 +790,8 @@ def join_partition(
     else:
         hit = np.zeros(len(lkeys), dtype=bool)
         gather = np.zeros(len(lkeys), dtype=np.intp)
-    lmask = left.columns[on].mask
-    if lmask is not None:
-        hit = hit & np.asarray(lmask)  # null left keys never match
-    return join_assemble(left, rmerged, gather, hit, how, on)
+    return join_assemble(left, rmerged, gather, hit, how, on,
+                         left_mask=left.columns[on].mask)
 
 
 def _decode_keys(col: Column) -> np.ndarray:

@@ -312,7 +312,11 @@ class FrameRuntime:
             ]
 
         def read_combine(node, inputs, results):
-            return PTable(list(results))
+            table = PTable(list(results))
+            for dim, key in getattr(eng, "join_dimensions", ()):
+                if dim == node.literals[0]:
+                    BK.build_join_index(table, key, self.backend_policy.resolve())
+            return table
 
         eng.register_op(
             "read_table",

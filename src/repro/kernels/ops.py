@@ -25,7 +25,7 @@ from .. import obs
 from . import ref
 from .filter_compact import filter_compact as _filter_pallas
 from .flash_attention import flash_attention as _attn_pallas
-from .join_probe import join_probe as _probe_pallas
+from .join_probe import merge_probe
 from .masked_stats import masked_stats as _stats_pallas
 from .segment_reduce import segment_reduce as _segment_pallas
 from .ssd_chunk import ssd_chunk_scan as _ssd_pallas
@@ -387,33 +387,34 @@ def argsort_f64(keys) -> jnp.ndarray:
 @obs.device_scope("join_probe")
 def _join_probe_xla(r_sorted: jnp.ndarray, l_keys: jnp.ndarray, m: int):
     pos = jnp.searchsorted(r_sorted, l_keys, side="left")
-    posc = jnp.clip(pos, 0, m - 1)
-    hit = r_sorted[posc] == l_keys
-    return posc, hit
+    hit = r_sorted[jnp.minimum(pos, m - 1)] == l_keys
+    return pos, hit
 
 
 def join_probe_padded(r_sorted, l_keys) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Probe each left key against the (small, ascending, unique) sorted right
-    key array: returns ``(pos, hit)`` with ``pos`` clipped to ``[0, m-1]``
-    ready to gather right rows, and ``hit`` marking exact matches.  Left keys
-    pad to a shape bucket; the right side stays exact-shape (one build — and
-    one jit specialisation — per broadcast dim table).  NaN left keys probe as
-    misses on every backend."""
+    """Probe each left key against the (ascending, unique) sorted right key
+    array: returns ``(pos, hit)``, ``pos`` the searchsorted-left position in
+    ``[0, m]`` and ``hit`` marking exact matches, in the order of
+    ``l_keys``.  The kernel backends take the keys in any order and are fast
+    in ``join_probe.band_order``'s.  Left keys pad to a shape bucket (keys
+    padded with NaN already are left as they are) and the outputs keep the
+    pads: the caller cuts them on the host, so no program is compiled per
+    exact length.  The right side stays exact-shape (one build, and one jit
+    specialisation, per broadcast dim table).  NaN left keys probe as misses
+    on every backend."""
     r_sorted = jnp.asarray(r_sorted, jnp.float32)
     l_keys = jnp.asarray(l_keys, jnp.float32)
     m = int(r_sorted.shape[0])
     if m == 0:
         raise ValueError("join_probe_padded: empty right side (caller gates)")
     n = l_keys.shape[0]
-    nb = pad_len(n)
-    lp = _pad1(l_keys, nb, jnp.nan)
+    lp = _pad1(l_keys, pad_len(n), jnp.nan)
     b = backend()
     if b == "xla":
         pos, hit = _join_probe_xla(r_sorted, lp, m)
     else:
-        pos, hit = _probe_pallas(lp, r_sorted, interpret=(b == "interpret"))
-        pos = jnp.clip(pos, 0, m - 1)
-    return pos[:n], hit[:n]
+        pos, hit = merge_probe(lp, r_sorted, interpret=(b == "interpret"))
+    return pos, hit
 
 
 # -- batched groupby partials -------------------------------------------------

@@ -55,6 +55,10 @@ NAMES = (
     # kernel dispatch (frame/runtime.py, frame/backend.py, kernels/ops.py)
     "dispatch.partial", "dispatch.prep", "dispatch.upload", "dispatch.call",
     "dispatch.wait", "dispatch.readback",
+    # join host side (frame/backend.py, frame/blocking.py): the right side's
+    # sort and upload, once per right table; the row order and right-column
+    # gathers of each joined partition
+    "join.build", "join.assemble",
     # background worker (core/engine.py)
     "worker.pick", "worker.fetch", "worker.execute", "worker.store",
 )

@@ -520,3 +520,91 @@ def test_real_mode_background_busy_accrues(catalog):
         _time.sleep(0.01)
     s.engine.stop_background()
     assert s.engine.metrics.background_busy_s > 0
+
+
+# --------------------------------------------------------------------------- #
+# a TPC-H-shaped star through the session: line items joined to orders and  #
+# customers (Q3's shape) and to parts (Q14's, over every line)               #
+# --------------------------------------------------------------------------- #
+
+
+@pytest.fixture(scope="module")
+def star_catalog():
+    from repro.frame import Catalog, ColSpec, TableSpec
+
+    cat = Catalog()
+    cat.register(TableSpec("lineitem", 40_000, (
+        ColSpec("l_orderkey", "int", low=0, high=10_000),
+        ColSpec("l_partkey", "int", low=0, high=2_000),
+        ColSpec("l_quantity", "int", low=1, high=51),
+        ColSpec("l_extendedprice", "float", low=900.0, high=105_000.0),
+        ColSpec("l_discount", "float", low=0.0, high=0.1),
+        ColSpec("l_shipdate", "int", low=0, high=2526),
+    ), seed=11))
+    cat.register(TableSpec("orders", 10_000, (
+        ColSpec("o_orderkey", "key"),
+        ColSpec("o_custkey", "int", low=0, high=3_000),
+        ColSpec("o_orderdate", "int", low=0, high=2406),
+        ColSpec("o_totalprice", "float", low=857.71, high=555_285.16),
+    ), seed=12))
+    cat.register(TableSpec("customer", 3_000, (
+        ColSpec("c_custkey", "key"),
+        ColSpec("c_mktsegment", "cat", n_categories=5),
+        ColSpec("c_acctbal", "float", low=-999.99, high=9_999.99),
+    ), seed=13))
+    cat.register(TableSpec("part", 2_000, (
+        ColSpec("p_partkey", "key"),
+        ColSpec("p_brand", "cat", n_categories=25),
+        ColSpec("p_size", "int", low=1, high=51),
+    ), seed=14))
+    return cat
+
+
+def _star_program(catalog, backend):
+    """Q3's shape (a ship-date window joined to orders, an order-date filter,
+    joined to customers, revenue by segment) and Q14's over every line
+    (joined to parts, by brand): the joined frames and their groupbys."""
+    s = Session(catalog=catalog, mode="sim", kernel_backend=backend)
+    li = s.read_table("lineitem")
+    q3 = li[li["l_shipdate"].between(1000, 1400)]
+    q3["rev"] = q3["l_extendedprice"] * 1.05
+    q3["o_orderkey"] = q3["l_orderkey"] * 1
+    q3 = q3.join(s.read_table("orders"), on="o_orderkey")
+    q3 = q3[q3["o_orderdate"] < 1200]
+    q3["c_custkey"] = q3["o_custkey"] * 1
+    q3 = q3.join(s.read_table("customer"), on="c_custkey")
+    q14 = s.read_table("lineitem")
+    q14["promo"] = q14["l_extendedprice"] * 0.97
+    q14["p_partkey"] = q14["l_partkey"] * 1
+    q14 = q14.join(s.read_table("part"), on="p_partkey")
+    return {
+        "q3_rows": s.show(q3).to_pydict(),
+        "q3": s.show(q3.groupby("c_mktsegment").agg(
+            {"rev": "sum", "l_discount": "mean"})).to_pydict(),
+        "q14_all_rows": s.show(q14).to_pydict(),
+        "q14_all": s.show(q14.groupby("p_brand").agg(
+            {"promo": "sum", "l_quantity": "mean"})).to_pydict(),
+    }
+
+
+def test_star_session_parity(star_catalog):
+    """The star's joins on the pallas kernels (interpret mode) select and
+    assemble exactly the rows numpy does, every joined value bit for bit;
+    the groupbys over them agree to the kernels' float32 sums."""
+    ref = _star_program(star_catalog, "numpy")
+    BK.reset_served_counts()
+    got = _star_program(star_catalog, "interpret")
+    assert BK.served_counts().get(("join", "interpret"), 0) >= 3
+    assert len(ref["q14_all_rows"]["p_brand"]) == 40_000  # every key hits
+    for q in ("q3_rows", "q14_all_rows"):
+        assert list(got[q]) == list(ref[q])
+        for col in ref[q]:
+            np.testing.assert_array_equal(got[q][col], ref[q][col], err_msg=f"{q}/{col}")
+    for q in ("q3", "q14_all"):
+        assert list(got[q]) == list(ref[q])
+        for col in ref[q]:
+            r, g = np.asarray(ref[q][col]), np.asarray(got[q][col])
+            if r.dtype.kind in "OU":
+                np.testing.assert_array_equal(g, r, err_msg=f"{q}/{col}")
+            else:
+                np.testing.assert_allclose(g, r, rtol=1e-5, err_msg=f"{q}/{col}")

@@ -204,3 +204,62 @@ def test_compile_clock_splits_phases():
     assert got["trace_n"] >= 1 and got["lower_n"] >= 1 and got["compile_n"] >= 1
     assert got["trace_s"] > 0 and got["lower_s"] > 0 and got["compile_s"] > 0
     assert clock.seconds >= got["compile_s"]
+
+
+def test_join_spans_build_once_and_assemble_per_partition():
+    """A join on the kernel path opens ``join.build`` once per right table
+    and engine (the sorted keys stay on the device for every later probe),
+    ``join.assemble`` once per joined partition, and its ``dispatch.call``
+    carries the right side's size for the probe's roofline."""
+    cat = Catalog()
+    cat.register(TableSpec("facts", 6_000, (
+        ColSpec("k", "int", low=0, high=500), ColSpec("x", "float")), seed=3))
+    cat.register(TableSpec("dim", 400, (ColSpec("k", "key"), ColSpec("w", "float")), seed=4))
+    s = Session(catalog=cat, mode="sim", kernel_backend="interpret")
+    facts, dim = s.read_table("facts"), s.read_table("dim")
+    nparts = len(s.engine.value_of(facts.node).partitions)
+    assert nparts > 1
+    sid0 = last_sid()
+    s.show(facts.join(dim, on="k").describe())
+    s.show(facts[facts["x"] > 0.5].join(dim, on="k").describe())
+    recs = spans_since(sid0)
+    builds = [r for r in recs if r.name == "join.build"]
+    assert len(builds) == 1
+    assert builds[0].attrs["right_rows"] == 400 and builds[0].attrs["bytes"] == 1600
+    assemblies = [r for r in recs if r.name == "join.assemble"]
+    assert len(assemblies) == 2 * nparts
+    assert all(r.attrs["cols"] == 1 for r in assemblies)
+    calls = [r for r in recs if r.name == "dispatch.call" and r.attrs["family"] == "join"]
+    assert len(calls) == 2 * nparts
+    assert all(r.attrs["right_rows"] == 400 for r in calls)
+    # another engine over the same catalog builds its own
+    t = Session(catalog=cat, mode="sim", kernel_backend="interpret")
+    sid1 = last_sid()
+    t.show(t.read_table("facts").join(t.read_table("dim"), on="k").describe())
+    assert sum(r.name == "join.build" for r in spans_since(sid1)) == 1
+
+
+def test_declared_dimension_builds_its_join_index_at_read():
+    """An engine told the star's dimensions builds a dimension's join index
+    (``join.build``) when it reads the table; its joins then build nothing
+    and answer as an engine that builds in the first join."""
+    cat = Catalog()
+    cat.register(TableSpec("facts", 6_000, (
+        ColSpec("k", "int", low=0, high=500), ColSpec("x", "float")), seed=3))
+    cat.register(TableSpec("dim", 400, (ColSpec("k", "key"), ColSpec("w", "float")), seed=4))
+    s = Session(catalog=cat, mode="sim", kernel_backend="interpret",
+                join_dimensions=[("dim", "k")])
+    sid0 = last_sid()
+    s.engine.value_of(s.read_table("dim").node)
+    builds = [r for r in spans_since(sid0) if r.name == "join.build"]
+    assert len(builds) == 1 and builds[0].attrs["right_rows"] == 400
+    sid1 = last_sid()
+    got = s.show(s.read_table("facts").join(s.read_table("dim"), on="k")).to_pydict()
+    recs = spans_since(sid1)
+    assert not any(r.name == "join.build" for r in recs)
+    assert any(r.name == "join.assemble" for r in recs)
+    t = Session(catalog=cat, mode="sim", kernel_backend="interpret")
+    want = t.show(t.read_table("facts").join(t.read_table("dim"), on="k")).to_pydict()
+    assert list(got) == list(want)
+    for col in want:
+        np.testing.assert_array_equal(got[col], want[col])

@@ -19,9 +19,8 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from repro.frame import dist
-from repro.kernels import (
-    filter_compact, join_probe, masked_stats, ops, segment_reduce, topk,
-)
+from repro.kernels import filter_compact, masked_stats, ops, segment_reduce, topk
+from repro.kernels.join_probe import merge_probe
 
 ROOT = Path(__file__).resolve().parents[1]
 DIM_ROWS = 1 << 16
@@ -94,7 +93,7 @@ KERNELS = {
         (S((n,), jnp.float32),),
     ),
     "join_probe": lambda n, S: (
-        join_probe,
+        merge_probe,
         (S((n,), jnp.float32), S((DIM_ROWS,), jnp.float32)),
     ),
 }
@@ -115,6 +114,32 @@ def test_filter_compact_stitches_in_the_kernel_for_v5e(n, chip, bucket):
     text = _compiled_text(filter_compact, chip((n,), jnp.float32), chip((n,), jnp.bool_))
     assert "tpu_custom_call" in text
     assert " sort(" not in text and " scatter(" not in text
+
+
+# the star's dimensions: TPC-H's orders and part at scale factor 1
+ORDERS_ROWS, PART_ROWS = 1_500_000, 200_000
+
+
+@pytest.mark.parametrize("n", [1 << 15, 1 << 17, 1 << 21])
+def test_join_probe_padded_sorts_nothing_for_v5e(n, chip):
+    """The probe's entry point at the star cell's left buckets (a month of
+    lines, a quarter, a whole partition) against orders: the Pallas merge
+    and no XLA sort, whose compile takes the TPU compiler tens of seconds
+    at these lengths (the host puts the keys in band order)."""
+    with ops.local_backend("pallas"):
+        text = _compiled_text(ops.join_probe_padded,
+                              chip((ORDERS_ROWS,), jnp.float32), chip((n,), jnp.float32))
+    assert "tpu_custom_call" in text and " sort(" not in text
+
+
+@pytest.mark.parametrize("m", [ORDERS_ROWS, PART_ROWS])
+@pytest.mark.parametrize("n", [1 << 15, 1 << 17, 1 << 21])
+def test_join_probe_merge_compiles_for_v5e(n, m, chip):
+    """The merge kernel against a 1.5M-row orders and a 200k-row part
+    dimension: the Pallas call, and no sort of the right side."""
+    text = _compiled_text(merge_probe,
+                          chip((n,), jnp.float32), chip((m,), jnp.float32))
+    assert "tpu_custom_call" in text and " sort(" not in text
 
 
 # the backend-dispatching entry points that wrap the kernels on the pallas
