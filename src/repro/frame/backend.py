@@ -963,7 +963,7 @@ def _join_build_cached(right: "PTable", on: str):
             sp.attrs["bytes"] = r_dev.nbytes
             # one slot past the end: a probe position of m (a key above every
             # right key, never a hit) gathers in range
-            entry = (rmerged, r_sorted, np.append(r_order, 0), r_dev)
+            entry = (rmerged, r_sorted, np.append(r_order, 0).astype(np.int32), r_dev)
     cache[on] = entry
     return entry
 
@@ -1031,8 +1031,8 @@ def join_partition(
         with _call("join", bk, [left], right_rows=len(r_sorted)), _kernel(bk):
             out = ops.join_probe_padded(r_dev, lkeys)
         pos, hit = (a[:left.nrows] for a in _fetch(out))
-        return B.join_assemble(left, rmerged, r_order[pos], hit, how, on,
-                               perm=perm, left_mask=lcol.mask)
+        return B.join_assemble(left, rmerged, pos, hit, how, on, perm=perm,
+                               left_mask=lcol.mask, lookup=r_order)
 
     return _guarded(
         "join", bk, _run, lambda: B.join_partition(left, right, on, how)

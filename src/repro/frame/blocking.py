@@ -17,6 +17,7 @@ import numpy as np
 
 from .. import obs
 from ..core.scheduler import sample_first_order
+from ..kernels.join_probe import order_cuts, over_chunks
 from .table import Column, Partition, PTable
 
 
@@ -725,36 +726,56 @@ def join_assemble(
     on: str,
     perm: Optional[np.ndarray] = None,
     left_mask: Optional[np.ndarray] = None,
+    lookup: Optional[np.ndarray] = None,
 ) -> Partition:
     """Shared tail of every join path (numpy probe and kernel probe): row
     selection plus the right-column gather.  ``gather`` holds in-range row
-    indices into ``rmerged``; rows with ``hit`` False are forced to index 0 so
-    every backend assembles bit-identical partitions.  With ``perm`` (the
-    kernel probe's band order) entry ``i`` of ``gather`` and ``hit``
-    belongs to left row ``perm[i]``.  ``left_mask`` marks the left rows whose
-    key is valid: a null key never matches."""
-    with obs.span("join.assemble", rows=left.nrows, cols=len(rmerged.order) - 1):
-        n = left.nrows
-        if perm is not None:
-            g = np.empty(n, gather.dtype)
-            g[perm] = gather
-            h = np.empty(n, bool)
-            h[perm] = hit
-            gather, hit = g, h
-        if left_mask is not None:
-            hit = hit & np.asarray(left_mask)
-        if how == "inner":
-            if not hit.all():  # every row matched: the left columns stay as they are
-                keep = np.nonzero(hit)[0]
-                left = left.take(keep)
-                gather = gather[keep]
-                hit = hit[keep]
-        elif how != "left":
-            raise ValueError(f"unsupported join how={how!r}")
-        gather = np.where(hit, gather, 0)
-        miss = ~np.asarray(hit)
+    indices into ``rmerged``, or with ``lookup`` in-range positions in
+    ``lookup``, which holds the rows; rows with ``hit`` False are forced to
+    index 0 so every backend assembles bit-identical partitions.  With
+    ``perm`` (the kernel probe's band order) entry ``i`` of ``gather`` and
+    ``hit`` belongs to left row ``perm[i]``.  ``left_mask`` marks the left
+    rows whose key is valid: a null key never matches.
+
+    The work runs on ``band_order``'s ranges of rows (``order_cuts``): one
+    range on the calling thread below ``2 · ORDER_CHUNK`` rows, else each on
+    the ordering's pool.  In ``band_order``'s ranges the entries of ``perm``
+    are the range's own rows, so a range's return to row order writes
+    inside it; any ``perm`` gives the same result."""
+    if how not in ("inner", "left"):
+        raise ValueError(f"unsupported join how={how!r}")
+    n = left.nrows
+    cuts = order_cuts(n)
+    with obs.span("join.assemble", rows=n, cols=len(rmerged.order) - 1,
+                  chunks=len(cuts) - 1):
+        hit = np.asarray(hit)
+        rows = np.empty(n, np.int32)  # each left row's right row, -1 for a miss
+        miss = np.empty(n, bool)
+
+        def to_rows(a: int, b: int) -> None:
+            idx = gather[a:b] if lookup is None else lookup[gather[a:b]]
+            idx = np.where(hit[a:b], idx, -1)
+            if perm is None:
+                rows[a:b] = idx
+            else:
+                rows[perm[a:b]] = idx
+
+        def misses(a: int, b: int) -> None:
+            np.less(rows[a:b], 0, out=miss[a:b])
+            if left_mask is not None:
+                miss[a:b] |= ~left_mask[a:b]
+            np.copyto(rows[a:b], 0, where=miss[a:b])
+
+        over_chunks(to_rows, cuts)
+        over_chunks(misses, cuts)
+        if how == "inner" and miss.any():
+            keep = np.flatnonzero(~miss)
+            left = left.take(keep)
+            rows = rows[keep]
+            cuts = np.linspace(0, len(keep), len(cuts)).astype(np.intp)
         cols = dict(left.columns)
         order = list(left.order)
+        srcs, outs = [], []
         for name in rmerged.order:
             if name == on:
                 continue
@@ -767,14 +788,33 @@ def join_assemble(
                     dictionary=src.dictionary,
                 )
             else:
-                taken = src.take(np.asarray(gather))
-                if how == "left":
-                    mask = taken.valid_mask() & ~miss
-                    taken = Column(data=taken.data, mask=mask,
-                                   dictionary=taken.dictionary)
+                masked = how == "left" or src.mask is not None
+                taken = Column(
+                    data=np.empty(left.nrows, src.data.dtype),
+                    mask=np.empty(left.nrows, bool) if masked else None,
+                    dictionary=src.dictionary,
+                )
+                srcs.append(src)
+                outs.append(taken)
             out_name = name if name not in cols else f"{name}_right"
             cols[out_name] = taken
             order.append(out_name)
+
+        def take(a: int, b: int) -> None:
+            idx = rows[a:b]  # in range: "clip" spares numpy's buffered check
+            for src, out in zip(srcs, outs):
+                np.take(src.data, idx, out=out.data[a:b], mode="clip")
+                if out.mask is None:
+                    continue
+                if src.mask is None:
+                    np.logical_not(miss[a:b], out=out.mask[a:b])
+                    continue
+                np.take(src.mask, idx, out=out.mask[a:b], mode="clip")
+                if how == "left":
+                    out.mask[a:b] &= ~miss[a:b]
+
+        if srcs:
+            over_chunks(take, cuts)
         return Partition(cols, order)
 
 
@@ -783,15 +823,13 @@ def join_partition(
 ) -> Partition:
     rmerged, r_sorted, r_order = join_build(right, on)
     lkeys = _decode_keys(left.columns[on])
-    if len(r_sorted):
-        pos = np.clip(np.searchsorted(r_sorted, lkeys), 0, len(r_sorted) - 1)
-        hit = r_sorted[pos] == lkeys
-        gather = r_order[pos]
-    else:
-        hit = np.zeros(len(lkeys), dtype=bool)
-        gather = np.zeros(len(lkeys), dtype=np.intp)
-    return join_assemble(left, rmerged, gather, hit, how, on,
-                         left_mask=left.columns[on].mask)
+    lmask = left.columns[on].mask
+    if not len(r_sorted):
+        return join_assemble(left, rmerged, np.zeros(len(lkeys), np.intp),
+                             np.zeros(len(lkeys), bool), how, on, left_mask=lmask)
+    pos = np.clip(np.searchsorted(r_sorted, lkeys), 0, len(r_sorted) - 1)
+    return join_assemble(left, rmerged, pos, r_sorted[pos] == lkeys, how, on,
+                         left_mask=lmask, lookup=r_order)
 
 
 def _decode_keys(col: Column) -> np.ndarray:

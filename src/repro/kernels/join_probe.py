@@ -142,6 +142,25 @@ def _order_pool() -> ThreadPoolExecutor:
     return ThreadPoolExecutor(ORDER_THREADS, thread_name_prefix="band_order")
 
 
+def order_cuts(n: int) -> np.ndarray:
+    """The row ranges ``[cuts[i], cuts[i + 1])`` that ``band_order`` orders
+    each on its own: one below ``2 · ORDER_CHUNK`` rows, else up to
+    ``ORDER_THREADS`` of at least ``ORDER_CHUNK`` rows each."""
+    chunks = max(1, min(ORDER_THREADS, n // ORDER_CHUNK))
+    return np.linspace(0, n, chunks + 1).astype(np.intp)
+
+
+def over_chunks(fn, cuts) -> None:
+    """``fn(a, b)`` for each range of ``cuts``: one range on the calling
+    thread, more on the ordering's pool, returning when all are done and
+    raising what a range raised.  ``fn`` opens no spans, so the caller's
+    span holds the wait."""
+    if len(cuts) == 2:
+        fn(int(cuts[0]), int(cuts[1]))
+        return
+    list(_order_pool().map(fn, cuts[:-1], cuts[1:]))
+
+
 def band_order(keys, lo: float, hi: float) -> Tuple[np.ndarray, np.ndarray]:
     """``(perm, keys[perm] as f32)``, on the host: the order in which to hand
     the left keys to ``merge_probe``.  The keys fall in ``BANDS`` value bands
@@ -149,21 +168,15 @@ def band_order(keys, lo: float, hi: float) -> Tuple[np.ndarray, np.ndarray]:
     ascending, and keep their row order within a band; keys below the range,
     ``-inf`` and NaN fall in the first band, keys above it and ``+inf`` in
     the last.  One stable argsort of a byte per key: a single radix pass in
-    numpy.  From ``2 · ORDER_CHUNK`` keys up, up to ``ORDER_THREADS`` chunks
-    are ordered at once, each on its own, one after another: a band of a
-    chunk still holds over a thousand keys, so the tiles stay as narrow."""
+    numpy.  Each range of ``order_cuts`` is ordered on its own, up to
+    ``ORDER_THREADS`` at once, one after another: a band of a chunk still
+    holds over a thousand keys, so the tiles stay as narrow, and the entries
+    of ``perm`` in a range are that range's rows."""
     k = np.asarray(keys)
     n = k.shape[0]
     perm, out = np.empty(n, np.intp), np.empty(n, np.float32)
-    chunks = min(ORDER_THREADS, n // ORDER_CHUNK)
-    if chunks < 2:
-        _order_into(k, lo, hi, perm, out, 0)
-        return perm, out
-    cuts = np.linspace(0, n, chunks + 1).astype(np.intp)
-    done = _order_pool().map(
-        lambda a, b: _order_into(k[a:b], lo, hi, perm[a:b], out[a:b], a),
-        cuts[:-1], cuts[1:])
-    list(done)  # raises what a chunk raised
+    over_chunks(lambda a, b: _order_into(k[a:b], lo, hi, perm[a:b], out[a:b], a),
+                order_cuts(n))
     return perm, out
 
 

@@ -7,6 +7,8 @@ The accelerated backends accumulate in float32, so numeric agreement is to
 ~1e-4 relative; structural results (keys, row selections, orderings, counts)
 must match exactly.
 """
+import importlib
+
 import numpy as np
 import pytest
 
@@ -204,6 +206,72 @@ def test_join_parity(table, dim_table, backend, how):
             miss = np.asarray(part.columns["i"].data) >= 40
             assert miss.any()
             assert not got.columns["w"].valid_mask()[miss].any()
+
+
+@pytest.mark.parametrize("backend", CPU_BACKENDS)
+@pytest.mark.parametrize("how", ["inner", "left"])
+@pytest.mark.parametrize("case", ["all_hit", "misses", "null_keys"])
+def test_chunked_join_assembly_parity(monkeypatch, backend, how, case):
+    """From ``2 · ORDER_CHUNK`` rows the probe's band order and the join's
+    assembly run in chunks on a pool of threads: the joined partition equals
+    the one-chunk numpy join's bit for bit, with misses (dropped by inner,
+    null in left), null left keys, null right values and a string right
+    column, and an inner join that hits every row keeps the left columns."""
+    from repro.frame.table import Column, Partition
+    JP = importlib.import_module("repro.kernels.join_probe")  # the package exports the function
+
+    rng = np.random.default_rng(11)
+    n, m = 10_000, 600
+    keys = rng.integers(0, m if case == "all_hit" else m + m // 4, n)
+    valid = rng.random(n) > 0.1 if case == "null_keys" else None
+    left = Partition({"i": Column(keys, valid), "x": Column(rng.normal(size=n))})
+    w = rng.normal(size=m)
+    w[::7] = np.nan
+    right = from_pydict({
+        "i": rng.permutation(m),  # the build's row order is not the keys'
+        "w": w,
+        "label": np.array([f"p{j}" for j in range(m)]),
+    })
+    ref = B.join_partition(left, right, "i", how)
+    monkeypatch.setattr(JP, "ORDER_CHUNK", 1024)
+    assert len(JP.order_cuts(n)) - 1 == 4
+    got = BK.join_partition(left, right, "i", how, backend=backend)
+    _partitions_equal(got, ref)
+    assert got.columns["label"].dictionary is right.partitions[0].columns["label"].dictionary
+    assert (got.nrows == n) == (how == "left" or case == "all_hit")
+    if how == "inner" and case == "all_hit":
+        assert got.columns["x"] is left.columns["x"]
+
+
+def test_chunked_join_assembly_under_concurrent_callers(monkeypatch):
+    """Callers on several threads share the ordering's pool: each chunked
+    assembly still equals its one-chunk reference, with the interpreter
+    switching threads every few microseconds."""
+    import sys
+    from concurrent.futures import ThreadPoolExecutor
+
+    from repro.frame.table import Column, Partition
+
+    JP = importlib.import_module("repro.kernels.join_probe")
+    rng = np.random.default_rng(5)
+    n, m = 8_000, 300
+    right = from_pydict({"i": rng.permutation(m), "w": rng.normal(size=m)})
+    lefts = [Partition({"i": Column(rng.integers(0, m + 50, n)),
+                        "x": Column(rng.normal(size=n))}) for _ in range(12)]
+    refs = [B.join_partition(p, right, "i", how)
+            for p, how in zip(lefts, ["inner", "left"] * 6)]
+    monkeypatch.setattr(JP, "ORDER_CHUNK", 1024)
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        with ThreadPoolExecutor(8) as callers:
+            futs = [callers.submit(B.join_partition, p, right, "i", how)
+                    for p, how in zip(lefts, ["inner", "left"] * 6)]
+            got = [f.result(timeout=60) for f in futs]
+    finally:
+        sys.setswitchinterval(interval)
+    for g, r in zip(got, refs):
+        _partitions_equal(g, r)
 
 
 @pytest.mark.parametrize("backend", CPU_BACKENDS)

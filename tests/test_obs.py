@@ -3,6 +3,7 @@ ring's bound, self time; spans in a profiler trace; the device scope of a
 kernel; and the spans of a real interaction under its request root."""
 import collections
 import glob
+import importlib
 import os
 import re
 import threading
@@ -228,7 +229,7 @@ def test_join_spans_build_once_and_assemble_per_partition():
     assert builds[0].attrs["right_rows"] == 400 and builds[0].attrs["bytes"] == 1600
     assemblies = [r for r in recs if r.name == "join.assemble"]
     assert len(assemblies) == 2 * nparts
-    assert all(r.attrs["cols"] == 1 for r in assemblies)
+    assert all(r.attrs["cols"] == 1 and r.attrs["chunks"] == 1 for r in assemblies)
     calls = [r for r in recs if r.name == "dispatch.call" and r.attrs["family"] == "join"]
     assert len(calls) == 2 * nparts
     assert all(r.attrs["right_rows"] == 400 for r in calls)
@@ -237,6 +238,34 @@ def test_join_spans_build_once_and_assemble_per_partition():
     sid1 = last_sid()
     t.show(t.read_table("facts").join(t.read_table("dim"), on="k").describe())
     assert sum(r.name == "join.build" for r in spans_since(sid1)) == 1
+
+
+def test_join_assemble_counts_its_chunks_and_opens_no_span_on_the_pool(monkeypatch):
+    """From ``2 · ORDER_CHUNK`` rows a joined partition is assembled in
+    ``band_order``'s chunks on its pool: ``join.assemble`` still opens once
+    per joined partition, on the interacting thread, and counts the
+    chunks; the pool's threads open no span."""
+    JP = importlib.import_module("repro.kernels.join_probe")  # the package exports the function
+
+    monkeypatch.setattr(JP, "ORDER_CHUNK", 1024)
+    cat = Catalog()
+    cat.register(TableSpec("facts", 12_000, (
+        ColSpec("k", "int", low=0, high=500), ColSpec("x", "float")), seed=3))
+    cat.register(TableSpec("dim", 400, (ColSpec("k", "key"), ColSpec("w", "float")), seed=4))
+    s = Session(catalog=cat, mode="sim", kernel_backend="interpret")
+    facts, dim = s.read_table("facts"), s.read_table("dim")
+    lengths = [p.nrows for p in s.engine.value_of(facts.node).partitions]
+    sid0 = last_sid()
+    s.show(facts.join(dim, on="k").describe())
+    recs = spans_since(sid0)
+    assemblies = [r for r in recs if r.name == "join.assemble"]
+    assert sorted(r.attrs["rows"] for r in assemblies) == sorted(lengths)
+    assert all(r.attrs["chunks"] == len(JP.order_cuts(r.attrs["rows"])) - 1
+               for r in assemblies)
+    assert max(r.attrs["chunks"] for r in assemblies) > 1
+    assert min(r.attrs["chunks"] for r in assemblies) == 1
+    pool = {t.ident for t in JP._order_pool()._threads}
+    assert pool and not any(r.thread in pool for r in recs)
 
 
 def test_declared_dimension_builds_its_join_index_at_read():
