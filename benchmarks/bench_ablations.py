@@ -4,7 +4,6 @@
 * cache eviction       — paper Eq 3 verbatim vs corrected vs LRU vs size-only
 * partitioning         — think-time-aware (paper §5.1) vs fixed coarse/fine
 * speculation          — filter-literal-tweaking workload, on vs off
-* opportunistic serving— anticipated-prompt prefill warming (beyond-paper)
 """
 from __future__ import annotations
 
@@ -174,42 +173,6 @@ def speculation_ablation() -> Dict[str, Dict[str, float]]:
     return out
 
 
-def serving_ablation() -> Dict[str, Dict[str, float]]:
-    """Opportunistic serving (beyond-paper): anticipated prompts prefilled
-    during think time vs cold requests."""
-    from repro.configs import get_smoke_config
-    from repro.models import ShardCtx, init_model
-    from repro.serve import OpportunisticServer
-
-    cfg = get_smoke_config("smollm_360m")
-    params = init_model(cfg, ShardCtx(), seed=0)
-    rng = np.random.default_rng(0)
-    prompts = [tuple(int(x) for x in rng.integers(0, cfg.vocab, 24)) for _ in range(6)]
-
-    cold = OpportunisticServer(cfg, params, step_cost_s=0.05, prefill_cost_s=0.1)
-    for p in prompts:
-        cold.request(p, n_tokens=4)
-        cold.think(8.0)
-    cold_lat = float(
-        np.mean([r.latency_s for r in cold.metrics.interactions])
-    )
-
-    warm = OpportunisticServer(cfg, params, step_cost_s=0.05, prefill_cost_s=0.1)
-    for i, p in enumerate(prompts):
-        if i + 1 < len(prompts):
-            warm.anticipate(prompts[i + 1])  # predicted next request
-        warm.request(p, n_tokens=4)
-        warm.think(8.0)
-    warm_lat = float(
-        np.mean([r.latency_s for r in warm.metrics.interactions])
-    )
-    return {
-        "cold": {"mean_latency_s": round(cold_lat, 4)},
-        "anticipated": {"mean_latency_s": round(warm_lat, 4)},
-        "speedup": {"x": round(cold_lat / max(warm_lat, 1e-9), 2)},
-    }
-
-
 def run_all():
     rows = []
     for name, fn in (
@@ -217,7 +180,6 @@ def run_all():
         ("cache_eviction", cache_ablation),
         ("partitioning", partition_ablation),
         ("speculation", speculation_ablation),
-        ("opportunistic_serving", serving_ablation),
     ):
         t0 = time.perf_counter()
         out = fn()

@@ -21,8 +21,7 @@ dispatched before the previous batch's results are pulled back to host (JAX
 async dispatch), so device compute overlaps host-side finalisation.
 
 Operator semantics are supplied by an :class:`OpRuntime` registry (the frame
-layer registers dataframe operators; the serving layer registers decode /
-prefill steps).
+layer registers the dataframe operators).
 """
 from __future__ import annotations
 

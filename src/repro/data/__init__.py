@@ -1,11 +1,2 @@
-"""repro.data — deterministic synthetic streams + prefetching loader."""
-from .loader import PrefetchLoader
-from .synth import (
-    SynthSpec,
-    TraceEvent,
-    TraceSpec,
-    batch_at,
-    make_iterator,
-    poisson_trace,
-    spec_for,
-)
+"""repro.data — seeded multi-session interaction traces."""
+from .synth import TraceEvent, TraceSpec, poisson_trace

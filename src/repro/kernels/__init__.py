@@ -1,4 +1,4 @@
-"""repro.kernels — Pallas TPU kernels for the framework's compute hot spots.
+"""repro.kernels — Pallas TPU kernels for the dataframe engine's hot spots.
 
 Each kernel module contains the pl.pallas_call + BlockSpec implementation;
 `ref.py` holds the pure-jnp oracles; `ops.py` the backend-dispatching jit
@@ -6,14 +6,12 @@ wrappers used by library code.
 """
 from . import ops, ref
 from .filter_compact import filter_compact
-from .flash_attention import flash_attention
 from .join_probe import join_probe
 from .masked_stats import masked_stats
 from .segment_reduce import segment_reduce
-from .ssd_chunk import ssd_chunk_scan
 from .topk import topk
 
 __all__ = [
-    "ops", "ref", "flash_attention", "segment_reduce", "masked_stats",
-    "filter_compact", "topk", "ssd_chunk_scan", "join_probe",
+    "ops", "ref", "segment_reduce", "masked_stats", "filter_compact", "topk",
+    "join_probe",
 ]

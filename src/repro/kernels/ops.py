@@ -3,8 +3,8 @@
 Backend selection:
   * ``"pallas"``    — Mosaic lowering (real TPU),
   * ``"interpret"`` — Pallas interpret mode (CPU correctness; used by tests),
-  * ``"xla"``       — the pure-jnp reference math (CPU dry-run / fallback;
-                       same semantics, XLA-fused).
+  * ``"xla"``       — the pure-jnp reference math (CPU / fallback; same
+                       semantics, XLA-fused).
 
 Default: pallas on TPU backends, xla elsewhere — so library code can call
 these unconditionally and stay runnable on this CPU container while targeting
@@ -24,15 +24,12 @@ import numpy as np
 from .. import obs
 from . import ref
 from .filter_compact import filter_compact as _filter_pallas
-from .flash_attention import flash_attention as _attn_pallas
 from .join_probe import merge_probe
 from .masked_stats import masked_stats as _stats_pallas
 from .segment_reduce import segment_reduce as _segment_pallas
-from .ssd_chunk import ssd_chunk_scan as _ssd_pallas
 from .topk import topk as _topk_pallas
 
 _FORCED: Optional[str] = None
-_XLA_UNROLL = False  # roofline probes: unroll xla-path loops for exact flops
 _TLS = threading.local()  # per-thread override (scoped, race-free)
 
 
@@ -57,11 +54,6 @@ def local_backend(backend: Optional[str]):
         _TLS.forced = prev
 
 
-def set_xla_unroll(flag: bool) -> None:
-    global _XLA_UNROLL
-    _XLA_UNROLL = flag
-
-
 def backend() -> str:
     local = getattr(_TLS, "forced", None)
     if local is not None:
@@ -69,22 +61,6 @@ def backend() -> str:
     if _FORCED is not None:
         return _FORCED
     return "pallas" if jax.default_backend() == "tpu" else "xla"
-
-
-def attention(
-    q, k, v, causal: bool = True, window: Optional[int] = None,
-    scale: Optional[float] = None, q_offset: int = 0,
-):
-    b = backend()
-    if b == "xla":
-        return ref.attention_xla_chunked(
-            q, k, v, causal=causal, window=window, scale=scale,
-            q_offset=q_offset, unroll=_XLA_UNROLL,
-        )
-    return _attn_pallas(
-        q, k, v, causal=causal, window=window, scale=scale, q_offset=q_offset,
-        interpret=(b == "interpret"),
-    )
 
 
 def segment_reduce(keys, values, valid, num_buckets: int, mode: str = "sum"):
@@ -115,13 +91,6 @@ def topk(x, k: int, largest: bool = True):
     if b == "xla":
         return ref.topk_ref(x, k, largest)
     return _topk_pallas(x, k, largest=largest, interpret=(b == "interpret"))
-
-
-def ssd_scan(x, log_a, bmat, cmat, chunk: int = 128):
-    b = backend()
-    if b == "xla":
-        return ref.ssd_xla_chunked(x, log_a, bmat, cmat, chunk=chunk)
-    return _ssd_pallas(x, log_a, bmat, cmat, chunk=chunk, interpret=(b == "interpret"))
 
 
 # --------------------------------------------------------------------------- #

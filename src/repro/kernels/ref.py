@@ -1,18 +1,18 @@
 """Pure-jnp oracles for every Pallas kernel (the correctness contracts).
 
 Each function is the mathematically transparent reference the kernels are
-validated against (interpret=True on CPU; Mosaic on real TPUs).  These are
-also the XLA fallback paths used by the dry-run (CPU cannot lower Mosaic).
+validated against (interpret=True on CPU; Mosaic on real TPUs).  Several
+are also the ``xla`` backend's paths in ``ops.py`` (CPU cannot lower Mosaic).
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 
 # --------------------------------------------------------------------------- #
-# segment_reduce: groupby-aggregate partials / MoE combine                     #
+# segment_reduce: groupby-aggregate partials                                   #
 # --------------------------------------------------------------------------- #
 
 
@@ -102,184 +102,3 @@ def topk_ref(x: jnp.ndarray, k: int, largest: bool = True) -> jnp.ndarray:
     """Top-k values, sorted (descending if largest)."""
     s = jnp.sort(x)
     return s[-k:][::-1] if largest else s[:k]
-
-
-# --------------------------------------------------------------------------- #
-# flash attention (GQA, causal, sliding window)                                #
-# --------------------------------------------------------------------------- #
-
-
-def attention_ref(
-    q: jnp.ndarray,  # (B, Hq, Sq, D)
-    k: jnp.ndarray,  # (B, Hkv, Skv, D)
-    v: jnp.ndarray,  # (B, Hkv, Skv, D)
-    causal: bool = True,
-    window: Optional[int] = None,
-    scale: Optional[float] = None,
-    q_offset: int = 0,
-) -> jnp.ndarray:
-    """Grouped-query softmax attention oracle (f32 accumulation).
-
-    ``q_offset``: absolute position of q[0] (decode: Skv - Sq).
-    ``window``: sliding-window size (keys with q_pos - k_pos >= window masked).
-    """
-    B, Hq, Sq, D = q.shape
-    Hkv = k.shape[1]
-    group = Hq // Hkv
-    scale = scale if scale is not None else D ** -0.5
-    qf = q.astype(jnp.float32) * scale
-    kf = k.astype(jnp.float32)
-    vf = v.astype(jnp.float32)
-    kf = jnp.repeat(kf, group, axis=1)
-    vf = jnp.repeat(vf, group, axis=1)
-    logits = jnp.einsum("bhqd,bhkd->bhqk", qf, kf)
-    Skv = k.shape[2]
-    qpos = jnp.arange(Sq)[:, None] + q_offset
-    kpos = jnp.arange(Skv)[None, :]
-    mask = jnp.ones((Sq, Skv), dtype=bool)
-    if causal:
-        mask &= kpos <= qpos
-    if window is not None:
-        mask &= kpos > qpos - window
-    logits = jnp.where(mask[None, None], logits, -jnp.inf)
-    probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhqk,bhkd->bhqd", probs, vf)
-    return out.astype(q.dtype)
-
-
-def attention_xla_chunked(
-    q: jnp.ndarray,
-    k: jnp.ndarray,
-    v: jnp.ndarray,
-    causal: bool = True,
-    window: Optional[int] = None,
-    scale: Optional[float] = None,
-    q_offset: int = 0,
-    block_q: int = 512,
-    unroll: bool = False,
-) -> jnp.ndarray:
-    """Flash-style attention expressed in XLA: lax.scan over query blocks so
-    only a (B, H, block_q, Skv) logits buffer is ever live — the memory shape
-    the Pallas kernel has on TPU, for the CPU/dry-run path.  Same math as
-    :func:`attention_ref` (tested).  ``unroll=True`` replaces the scan with a
-    python loop — identical math/flops but no while-loop in the HLO, used by
-    the roofline probes (HLO cost analysis counts loop bodies once)."""
-    B, Hq, Sq, D = q.shape
-    Hkv, Skv = k.shape[1], k.shape[2]
-    group = Hq // Hkv
-    scale = scale if scale is not None else D ** -0.5
-    if Sq <= block_q:
-        return attention_ref(
-            q, k, v, causal=causal, window=window, scale=scale, q_offset=q_offset
-        )
-    while Sq % block_q:
-        block_q //= 2
-    nq = Sq // block_q
-    kf = jnp.repeat(k, group, axis=1)
-    vf = jnp.repeat(v, group, axis=1)
-    qb = q.reshape(B, Hq, nq, block_q, D).transpose(2, 0, 1, 3, 4)
-    kpos = jnp.arange(Skv)[None, :]
-
-    def body(_, args):
-        qi, i = args
-        qf = qi.astype(jnp.float32) * scale
-        logits = jnp.einsum(
-            "bhqd,bhkd->bhqk", qf, kf.astype(jnp.float32)
-        )
-        qpos = i * block_q + jnp.arange(block_q)[:, None] + q_offset
-        mask = jnp.ones((block_q, Skv), bool)
-        if causal:
-            mask &= kpos <= qpos
-        if window is not None:
-            mask &= kpos > qpos - window
-        logits = jnp.where(mask[None, None], logits, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1)
-        out = jnp.einsum("bhqk,bhkd->bhqd", probs, vf.astype(jnp.float32))
-        return None, out.astype(q.dtype)
-
-    if unroll:
-        outs = jnp.stack(
-            [body(None, (qb[i], jnp.asarray(i)))[1] for i in range(nq)]
-        )
-    else:
-        _, outs = jax.lax.scan(body, None, (qb, jnp.arange(nq)))
-    return outs.transpose(1, 2, 0, 3, 4).reshape(B, Hq, Sq, D)
-
-
-# --------------------------------------------------------------------------- #
-# Mamba-2 SSD (state-space duality), chunked                                   #
-# --------------------------------------------------------------------------- #
-
-
-def ssd_ref(
-    x: jnp.ndarray,  # (S, H, P)   head inputs
-    log_a: jnp.ndarray,  # (S, H)  per-step log decay (<= 0)
-    b: jnp.ndarray,  # (S, N)      input projection (shared across heads)
-    c: jnp.ndarray,  # (S, N)      output projection
-    h0: Optional[jnp.ndarray] = None,  # (H, N, P) initial state
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Sequential SSD oracle:  h_t = a_t h_{t-1} + b_t x_t^T ;  y_t = c_t h_t.
-
-    Returns (y (S,H,P), h_final (H,N,P)).
-    """
-    S, H, P = x.shape
-    N = b.shape[1]
-    h = jnp.zeros((H, N, P), jnp.float32) if h0 is None else h0.astype(jnp.float32)
-
-    def step(h, t):
-        a_t = jnp.exp(log_a[t]).astype(jnp.float32)  # (H,)
-        outer = jnp.einsum("n,hp->hnp", b[t].astype(jnp.float32),
-                           x[t].astype(jnp.float32))
-        h = a_t[:, None, None] * h + outer
-        y_t = jnp.einsum("n,hnp->hp", c[t].astype(jnp.float32), h)
-        return h, y_t
-
-    h, ys = jax.lax.scan(step, h, jnp.arange(S))
-    return ys.astype(x.dtype), h
-
-
-def ssd_xla_chunked(
-    x: jnp.ndarray,  # (S, H, P)
-    log_a: jnp.ndarray,  # (S, H)
-    b: jnp.ndarray,  # (S, N)
-    c: jnp.ndarray,  # (S, N)
-    chunk: int = 128,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """The chunked SSD algorithm in pure XLA: intra-chunk quadratic parts are
-    *batched over chunks* (parallel einsums, no sequential scan over S), and
-    only the tiny inter-chunk state recurrence is a lax.scan (nc steps).
-    Matches :func:`ssd_ref`; this is the dry-run/CPU counterpart of the
-    `ssd_chunk` Pallas kernel."""
-    S, H, P = x.shape
-    N = b.shape[1]
-    chunk = min(chunk, S)
-    while S % chunk:
-        chunk //= 2
-    nc = S // chunk
-    xf = x.astype(jnp.float32).reshape(nc, chunk, H, P)
-    la = log_a.astype(jnp.float32).reshape(nc, chunk, H)
-    bf = b.astype(jnp.float32).reshape(nc, chunk, N)
-    cf = c.astype(jnp.float32).reshape(nc, chunk, N)
-
-    cum = jnp.cumsum(la, axis=1)  # (nc, L, H)
-    diff = cum[:, :, None, :] - cum[:, None, :, :]  # (nc, L, L, H)
-    causal = jnp.tril(jnp.ones((chunk, chunk), bool))
-    lmask = jnp.where(causal[None, :, :, None], jnp.exp(diff), 0.0)
-    cb = jnp.einsum("nik,njk->nij", cf, bf)  # (nc, L, L)
-    y_intra = jnp.einsum(
-        "nijh,nij,njhp->nihp", lmask, cb, xf
-    )  # (nc, L, H, P)
-
-    decay_end = jnp.exp(cum[:, -1:, :] - cum)  # (nc, L, H)
-    s_local = jnp.einsum("nlk,nlh,nlhp->nhkp", bf, decay_end, xf)  # (nc,H,N,P)
-    chunk_decay = jnp.exp(cum[:, -1, :])  # (nc, H)
-
-    def scan_fn(h, inp):
-        d_k, s_k = inp
-        return d_k[:, None, None] * h + s_k, h
-
-    h0 = jnp.zeros((H, N, P), jnp.float32)
-    h_final, h_in = jax.lax.scan(scan_fn, h0, (chunk_decay, s_local))
-    y_off = jnp.einsum("nlk,nhkp->nlhp", cf, h_in) * jnp.exp(cum)[..., None]
-    y = (y_intra + y_off).reshape(S, H, P)
-    return y.astype(x.dtype), h_final

@@ -1,8 +1,2 @@
-"""repro.serve — prefill/decode serving + opportunistic sessions.
-
-Multi-tenant serving (``MultiTenantServer``) lives in its own module and
-imports only the core layer, so trace-replay benchmarks and tests can use it
-without pulling in the model stack."""
-from .engine import greedy_generate, make_serve_fns
+"""repro.serve — multi-tenant serving of frame programs over one shared engine."""
 from .multitenant import MultiTenantServer, TenantProgram
-from .session import OpportunisticServer

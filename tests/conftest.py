@@ -1,13 +1,23 @@
 import os
 import sys
 
-# single-device tests: do NOT force 512 host devices here (only dryrun does)
+# single-device tests: do NOT force host devices here (test_dist.py's
+# subprocesses do)
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import numpy as np
 import pytest
 
 from repro.frame import Catalog, ColSpec, Session, TableSpec
+from repro.kernels import ops
+
+
+@pytest.fixture(params=["xla", "interpret"])
+def ops_backend(request):
+    """Each kernel-op case under the xla backend and under Pallas interpret
+    mode, scoped to the test's thread."""
+    with ops.local_backend(request.param):
+        yield request.param
 
 
 @pytest.fixture()

@@ -18,6 +18,7 @@ plan-parity with sharded dispatch enabled.
 import os
 import subprocess
 import sys
+import textwrap
 
 import jax
 import numpy as np
@@ -382,3 +383,49 @@ def test_multidevice_suite_in_subprocess():
         timeout=900,
     )
     assert proc.returncode == 0, proc.stdout[-4000:] + proc.stderr[-2000:]
+
+
+def test_distributed_frame_ops_subprocess():
+    """shard_map describe/groupby over 8 fake devices match the oracle."""
+    code = textwrap.dedent(
+        """
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+        import jax, jax.numpy as jnp, numpy as np
+        from repro.frame.dist import (
+            make_distributed_describe, make_distributed_groupby_sum,
+            shard_column)
+        mesh = jax.make_mesh(
+            (8,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
+        rng = np.random.default_rng(0)
+        n, nb = 4096, 16
+        x = jnp.asarray(rng.normal(size=n), jnp.float32)
+        m = jnp.asarray(rng.uniform(size=n) > 0.25)
+        keys = jnp.asarray(rng.integers(0, nb, n), jnp.int32)
+        with jax.set_mesh(mesh):
+            desc = make_distributed_describe(mesh)
+            out = np.asarray(desc(shard_column(mesh, x), shard_column(mesh, m)))
+            xs = np.asarray(x)[np.asarray(m)]
+            assert abs(out[0] - xs.size) < 1e-3
+            assert abs(out[1] - xs.mean()) < 1e-4
+            assert abs(out[2] - xs.std(ddof=1)) < 1e-3
+            gb = make_distributed_groupby_sum(mesh, nb)
+            sums, counts = gb(shard_column(mesh, keys), shard_column(mesh, x),
+                              shard_column(mesh, m))
+            ref = np.zeros(nb); cnt = np.zeros(nb)
+            kk = np.asarray(keys); xx = np.asarray(x); mm = np.asarray(m)
+            for k, v, ok in zip(kk, xx, mm):
+                if ok:
+                    ref[k] += v; cnt[k] += 1
+            np.testing.assert_allclose(np.asarray(sums), ref, atol=1e-3)
+            np.testing.assert_allclose(np.asarray(counts), cnt)
+        print("DIST_FRAME_OK")
+        """
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", code],
+        capture_output=True, text=True, timeout=600,
+        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"},
+        cwd=".",
+    )
+    assert "DIST_FRAME_OK" in out.stdout, out.stderr[-2000:]

@@ -10,57 +10,13 @@ import pytest
 
 from repro.kernels import ref as R
 from repro.kernels.filter_compact import filter_compact
-from repro.kernels.flash_attention import flash_attention
 from repro.kernels.join_probe import BANDS, band_order, join_probe
 from repro.kernels.masked_stats import masked_stats
 from repro.kernels.segment_reduce import segment_reduce
-from repro.kernels.ssd_chunk import ssd_chunk_scan
 from repro.kernels.topk import topk
 
 RNG = np.random.default_rng(42)
 JP = importlib.import_module("repro.kernels.join_probe")  # the module, not the function
-
-
-# ---------------------------------------------------------------- attention --
-@pytest.mark.parametrize("B,Hq,Hkv,S,D", [
-    (1, 2, 2, 128, 64),    # MHA
-    (2, 8, 2, 256, 64),    # GQA 4:1
-    (1, 4, 1, 256, 128),   # MQA
-    (1, 3, 1, 128, 64),    # odd head count
-])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_flash_attention_sweep(B, Hq, Hkv, S, D, dtype):
-    q = jnp.asarray(RNG.normal(size=(B, Hq, S, D)), dtype)
-    k = jnp.asarray(RNG.normal(size=(B, Hkv, S, D)), dtype)
-    v = jnp.asarray(RNG.normal(size=(B, Hkv, S, D)), dtype)
-    out = flash_attention(q, k, v, causal=True, interpret=True)
-    ref = R.attention_ref(q, k, v, causal=True)
-    tol = 2e-5 if dtype == jnp.float32 else 2e-2
-    np.testing.assert_allclose(
-        np.asarray(out, np.float32), np.asarray(ref, np.float32), atol=tol, rtol=tol
-    )
-
-
-@pytest.mark.parametrize("causal,window", [(True, None), (False, None), (True, 64), (True, 128)])
-def test_flash_attention_masks(causal, window):
-    B, Hq, Hkv, S, D = 1, 4, 2, 256, 64
-    q = jnp.asarray(RNG.normal(size=(B, Hq, S, D)), jnp.float32)
-    k = jnp.asarray(RNG.normal(size=(B, Hkv, S, D)), jnp.float32)
-    v = jnp.asarray(RNG.normal(size=(B, Hkv, S, D)), jnp.float32)
-    out = flash_attention(q, k, v, causal=causal, window=window, interpret=True)
-    ref = R.attention_ref(q, k, v, causal=causal, window=window)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
-
-
-def test_flash_attention_decode_offset():
-    """Sq=1 decode against a long KV cache with q_offset."""
-    B, Hq, Hkv, S, D = 2, 4, 4, 512, 64
-    q = jnp.asarray(RNG.normal(size=(B, Hq, 1, D)), jnp.float32)
-    k = jnp.asarray(RNG.normal(size=(B, Hkv, S, D)), jnp.float32)
-    v = jnp.asarray(RNG.normal(size=(B, Hkv, S, D)), jnp.float32)
-    out = flash_attention(q, k, v, causal=True, q_offset=S - 1, interpret=True)
-    ref = R.attention_ref(q, k, v, causal=True, q_offset=S - 1)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
 # ------------------------------------------------------------- segment_reduce --
@@ -272,32 +228,3 @@ def test_topk_sweep(n, k, largest):
     out = topk(x, k, largest=largest, interpret=True)
     ref = R.topk_ref(x, k, largest=largest)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref))
-
-
-# ------------------------------------------------------------------------- ssd --
-@pytest.mark.parametrize("S,H,P,N,chunk", [
-    (128, 2, 16, 16, 32),
-    (256, 4, 32, 16, 64),
-    (256, 1, 64, 32, 128),
-])
-def test_ssd_chunk_sweep(S, H, P, N, chunk):
-    x = jnp.asarray(RNG.normal(size=(S, H, P)) * 0.5, jnp.float32)
-    la = jnp.asarray(-np.abs(RNG.normal(size=(S, H))) * 0.1, jnp.float32)
-    b = jnp.asarray(RNG.normal(size=(S, N)) * 0.3, jnp.float32)
-    c = jnp.asarray(RNG.normal(size=(S, N)) * 0.3, jnp.float32)
-    y, h = ssd_chunk_scan(x, la, b, c, chunk=chunk, interpret=True)
-    ry, rh = R.ssd_ref(x, la, b, c)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(ry), atol=3e-3)
-    np.testing.assert_allclose(np.asarray(h), np.asarray(rh), atol=3e-3)
-
-
-def test_ssd_chunk_invariance():
-    """Chunk size must not change the result (state-passing correctness)."""
-    S, H, P, N = 256, 2, 16, 16
-    x = jnp.asarray(RNG.normal(size=(S, H, P)) * 0.5, jnp.float32)
-    la = jnp.asarray(-np.abs(RNG.normal(size=(S, H))) * 0.1, jnp.float32)
-    b = jnp.asarray(RNG.normal(size=(S, N)) * 0.3, jnp.float32)
-    c = jnp.asarray(RNG.normal(size=(S, N)) * 0.3, jnp.float32)
-    y64, _ = ssd_chunk_scan(x, la, b, c, chunk=64, interpret=True)
-    y128, _ = ssd_chunk_scan(x, la, b, c, chunk=128, interpret=True)
-    np.testing.assert_allclose(np.asarray(y64), np.asarray(y128), atol=2e-3)

@@ -437,23 +437,3 @@ def test_multitenant_progressive_attribution_and_log():
     # non-progressive entry keeps its historical shape (now a cache hit)
     assert srv.interact("alice", root) == exact
     assert srv.schedule_log[-1] == ["interact", "alice", root.nid, "hit"]
-
-
-def test_serve_request_progressive_upgrades_to_exact():
-    pytest.importorskip("jax")
-    from repro.configs import get_smoke_config
-    from repro.models import ShardCtx, init_model
-    from repro.serve import OpportunisticServer
-
-    cfg = get_smoke_config("smollm_360m")
-    params = init_model(cfg, ShardCtx(), seed=0)
-    prompt = tuple(range(1, 17))
-
-    srv = OpportunisticServer(cfg, params, step_cost_s=0.05, prefill_cost_s=0.1)
-    exact = srv.request(prompt, n_tokens=4, tenant="a")
-
-    srv2 = OpportunisticServer(cfg, params, step_cost_s=0.05, prefill_cost_s=0.1)
-    pr = srv2.request(prompt, n_tokens=4, tenant="a", progressive=True)
-    assert pr.estimate().coverage < 1.0  # returned before decoding finished
-    got = pr.upgrade()
-    np.testing.assert_array_equal(got.tokens, exact.tokens)
